@@ -446,7 +446,6 @@ def make_sync_forward(cfg: GNNConfig, halo: HaloExchangeSpec, axis: str = "data"
 def make_sync_train_step(cfg: GNNConfig, halo: HaloExchangeSpec,
                          multilabel: bool, mesh: Mesh, lr: float = 1e-2):
     """shard_map train step: one partition per `data` device."""
-    from jax.experimental.shard_map import shard_map
     forward = make_sync_forward(cfg, halo)
 
     def loss_fn(params, t, my_idx, dropout_key):
@@ -471,12 +470,12 @@ def make_sync_train_step(cfg: GNNConfig, halo: HaloExchangeSpec,
                 loss[None])
 
     pspec = P("data")
-    # check_rep=False: pallas_call (the use_kernel aggregation path) has no
-    # shard_map replication rule; all inputs/outputs are explicitly sharded
-    # over `data`, so the check is vacuous here anyway
-    step = shard_map(local_step, mesh=mesh,
-                     in_specs=(pspec, pspec, pspec, pspec),
-                     out_specs=(pspec, pspec, pspec), check_rep=False)
+    # check_vma=False: pallas_call (the use_kernel aggregation path) has no
+    # varying-axes rule; all inputs/outputs are explicitly sharded over
+    # `data`, so the check is vacuous here anyway
+    step = jax.shard_map(local_step, mesh=mesh,
+                         in_specs=(pspec, pspec, pspec, pspec),
+                         out_specs=(pspec, pspec, pspec), check_vma=False)
     return jax.jit(step)
 
 
@@ -492,8 +491,6 @@ def train_sync(ds: NodeDataset, batch: PartitionBatch,
     (one partition per device); every layer refreshes halo activations via
     an all_gather, which is exactly the traffic Leiden-Fusion eliminates.
     Returns (params, global_embeddings [n, E])."""
-    from jax.experimental.shard_map import shard_map
-
     k = batch.k
     data_size = int(mesh.shape["data"])
     if data_size != k:
@@ -534,8 +531,9 @@ def train_sync(ds: NodeDataset, batch: PartitionBatch,
         return emb[None]
 
     pspec = P("data")
-    emb_fn = jax.jit(shard_map(eval_one, mesh=mesh, in_specs=(pspec, pspec),
-                               out_specs=pspec, check_rep=False))
+    emb_fn = jax.jit(jax.shard_map(eval_one, mesh=mesh,
+                                   in_specs=(pspec, pspec), out_specs=pspec,
+                                   check_vma=False))
     params, emb = apply_integration(
         params, integrate, lambda p: emb_fn(p, tensors), k)
     return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
@@ -588,7 +586,6 @@ def make_stale_train_steps(cfg: GNNConfig, halo: HaloExchangeSpec,
       halo refresh at all; used before the first exchange (period=∞), where
       it matches the local vmap step partition-for-partition.
     """
-    from jax.experimental.shard_map import shard_map
     forward = make_halo_forward(cfg, halo)
 
     def loss_of(refresh_mode):
@@ -626,17 +623,18 @@ def make_stale_train_steps(cfg: GNNConfig, halo: HaloExchangeSpec,
         return local_step
 
     pspec = P("data")
-    # check_rep=False: pallas_call (the use_kernel aggregation path) has no
-    # shard_map replication rule (same rationale as make_sync_train_step)
-    ex = shard_map(local_step_of("exchange"), mesh=mesh,
-                   in_specs=(pspec, pspec, pspec, pspec),
-                   out_specs=(pspec, pspec, pspec, pspec), check_rep=False)
-    st = shard_map(local_step_of("cached"), mesh=mesh,
-                   in_specs=(pspec, pspec, pspec, pspec, pspec),
-                   out_specs=(pspec, pspec, pspec), check_rep=False)
-    fz = shard_map(local_step_of("frozen"), mesh=mesh,
-                   in_specs=(pspec, pspec, pspec, pspec),
-                   out_specs=(pspec, pspec, pspec), check_rep=False)
+    # check_vma=False: pallas_call (the use_kernel aggregation path) has no
+    # varying-axes rule (same rationale as make_sync_train_step)
+    smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
+    ex = smap(local_step_of("exchange"),
+              in_specs=(pspec, pspec, pspec, pspec),
+              out_specs=(pspec, pspec, pspec, pspec))
+    st = smap(local_step_of("cached"),
+              in_specs=(pspec, pspec, pspec, pspec, pspec),
+              out_specs=(pspec, pspec, pspec))
+    fz = smap(local_step_of("frozen"),
+              in_specs=(pspec, pspec, pspec, pspec),
+              out_specs=(pspec, pspec, pspec))
     return {"exchange": jax.jit(ex), "stale": jax.jit(st),
             "frozen": jax.jit(fz)}
 
@@ -660,8 +658,6 @@ def train_stale(ds: NodeDataset, batch: PartitionBatch,
     exchange step, or the frozen step when no exchange ever happens) and
     ``"hlo_stale"`` (the between-exchange program — proven collective-free
     in tests). Returns (params, global_embeddings [n, E])."""
-    from jax.experimental.shard_map import shard_map
-
     k = batch.k
     data_size = int(mesh.shape["data"])
     if data_size != k:
@@ -750,8 +746,9 @@ def train_stale(ds: NodeDataset, batch: PartitionBatch,
         return emb[None]
 
     pspec = P("data")
-    emb_fn = jax.jit(shard_map(eval_one, mesh=mesh, in_specs=(pspec, pspec),
-                               out_specs=pspec, check_rep=False))
+    emb_fn = jax.jit(jax.shard_map(eval_one, mesh=mesh,
+                                   in_specs=(pspec, pspec), out_specs=pspec,
+                                   check_vma=False))
     params, emb = apply_integration(
         params, integrate, lambda p: emb_fn(p, tensors), k)
     return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,

@@ -1,8 +1,8 @@
 """Autotuned kernel configs: per-(backend, shape-bucket) tiling + strategy.
 
-PR 4 hard-coded ``NODE_TILE=512 / EDGE_BLOCK=256 / FEAT_TILE=128`` — one
-point in a search space whose optimum moves with the backend and the
-partition shape. This module owns that choice (DESIGN.md §14):
+The module constants ``NODE_TILE=512 / EDGE_BLOCK=256 / FEAT_TILE=128``
+are one point in a search space whose optimum moves with the backend and
+the partition shape. This module owns that choice (DESIGN.md §14):
 
 * **KernelConfig** — the tunable contract: a *strategy* plus tile sizes.
   Strategies:
@@ -11,7 +11,7 @@ partition shape. This module owns that choice (DESIGN.md §14):
     bias + relu in ONE ``pallas_call``, :mod:`repro.kernels.fused_layer`);
     the TPU default — it amortizes kernel-launch overhead and keeps the
     aggregate tile in VMEM through the dense epilogue.
-  - ``"pallas"`` — the unfused PR 4 aggregation kernel with tuned tiles;
+  - ``"pallas"`` — the unfused aggregation kernel with tuned tiles;
     the dense transform stays an XLA matmul.
   - ``"xla"`` — the same fused-layer math lowered directly through XLA
     (gather + segment-sum + dense epilogue under one jit). On backends
@@ -24,7 +24,7 @@ partition shape. This module owns that choice (DESIGN.md §14):
 * **shape buckets** — configs are keyed by ``(backend, bucket)`` where the
   bucket rounds N and E up to powers of two and F up to the lane multiple,
   so one tuning run covers every partition that pads into the same bucket
-  (the PR 2 fingerprint discipline applied to kernel shapes).
+  (the partition-fingerprint discipline applied to kernel shapes).
 
 * **disk cache** — tuning is paid once: results land in a JSON cache
   (``REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/autotune_cache.json``,
@@ -50,7 +50,7 @@ from repro import obs
 __all__ = [
     "KernelConfig", "ShapeBucket", "shape_bucket", "get_config", "autotune",
     "override", "candidate_space", "vmem_bytes", "cache_path",
-    "clear_memory_cache", "VMEM_BUDGET",
+    "clear_memory_cache", "interpret_mode", "VMEM_BUDGET",
 ]
 
 # Pallas TPU VMEM working-set ceiling the candidate filter enforces
@@ -122,19 +122,26 @@ def shape_bucket(n: int, e: int, f: int) -> ShapeBucket:
 
 def vmem_bytes(bucket: ShapeBucket, cfg: KernelConfig,
                f_out: Optional[int] = None) -> int:
-    """f32 VMEM working set of one fused-layer grid step (DESIGN.md §14):
-    the full gather column, the streamed edge granule, the resident
-    aggregate/output tiles, the weight block, and the dense accumulator."""
+    """f32 VMEM working set of one fused-layer grid step (DESIGN.md §14).
+
+    Pallas double-buffers every blocked operand: the streamed
+    ``[granule, FT]`` block of source rows (gathered by XLA before the
+    call), the granule's dst and weight rows (``[1, granule]``, padded to 8
+    sublanes), the ``[NT, 1]`` inverse-degree column (padded to 128 lanes),
+    the ``[FT, FO]`` weight block and ``[1, FO]`` bias, and the aggregate
+    and output tiles. Single copies: the ``[NT, FO]`` dense accumulator and
+    the ``[NT, EB]`` one-hot scatter with its iota. No term grows with N."""
     fo = f_out if f_out is not None else bucket.f
     ft = min(cfg.feat_tile, bucket.f)
     nt = min(cfg.node_tile, bucket.n)
-    gather_col = bucket.n * ft
-    edges = 3 * cfg.edge_granule          # src, dst, w (int32/f32 alike)
-    agg_tile = nt * ft
-    w_block = ft * fo
-    z_acc = nt * fo
-    out_tile = nt * fo
-    return 4 * (gather_col + edges + agg_tile + w_block + z_acc + out_tile)
+    granule = cfg.edge_granule
+    blocked = (granule * ft              # pre-gathered source rows
+               + 2 * 8 * granule         # dst, weight rows
+               + nt * 128                # inverse-degree column
+               + ft * fo + 8 * fo        # weight block, bias row
+               + nt * ft + nt * fo)      # aggregate, output tiles
+    single = nt * fo + 2 * nt * cfg.edge_block
+    return 4 * (2 * blocked + single)
 
 
 # ---------------------------------------------------------------------------
@@ -229,13 +236,22 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def fallback_config(backend: Optional[str] = None) -> KernelConfig:
-    """Untuned default: the fused Pallas kernel on TPU (PR 4's tile point),
-    the XLA lowering everywhere Pallas would run in interpret mode."""
-    backend = backend or _backend()
-    if backend == "tpu":
-        return KernelConfig(strategy="pallas_fused")
-    return KernelConfig(strategy="xla")
+def interpret_mode() -> bool:
+    """Whether Pallas calls run in the interpreter: everywhere but a TPU.
+
+    Decided from the backend alone, at trace time — no caller chooses, so
+    no TPU run can fall back to the interpreter."""
+    return _backend() != "tpu"
+
+
+def fallback_config(bucket: ShapeBucket,
+                    backend: Optional[str] = None) -> KernelConfig:
+    """Untuned default: the first candidate of :func:`candidate_space` —
+    on TPU the default fused-kernel tiling, or the first that passes the
+    VMEM filter when it does not; the XLA lowering where no tiling fits
+    and wherever Pallas would run in interpret mode. So the untuned path
+    never picks a config the tuner would refuse."""
+    return candidate_space(bucket, backend)[0]
 
 
 def get_config(n: int, e: int, f: int,
@@ -250,7 +266,7 @@ def get_config(n: int, e: int, f: int,
     hit = _memo.get((backend, bucket.key))
     if hit is not None:
         return hit
-    return fallback_config(backend)
+    return fallback_config(bucket, backend)
 
 
 # ---------------------------------------------------------------------------
@@ -260,10 +276,12 @@ def candidate_space(bucket: ShapeBucket,
                     backend: Optional[str] = None) -> List[KernelConfig]:
     """Deterministically-ordered candidates for one (backend, bucket).
 
-    TPU: the pallas strategies over a tile sweep, VMEM-filtered. Other
-    backends: the XLA strategy, plus the interpret-mode pallas points only
-    when ``REPRO_AUTOTUNE_EXHAUSTIVE=1`` (they are emulation, ~15x off —
-    measuring them by default just burns CI minutes)."""
+    TPU: the pallas strategies over a tile sweep, VMEM-filtered, with the
+    untuned default point (``KernelConfig(strategy="pallas_fused")``) first
+    when it fits. Other backends: the XLA strategy, plus the interpret-mode
+    pallas points only when ``REPRO_AUTOTUNE_EXHAUSTIVE=1`` (they are
+    emulation, ~15x off — measuring them by default just burns CI
+    minutes)."""
     backend = backend or _backend()
     if backend != "tpu":
         cands = [KernelConfig(strategy="xla")]
@@ -274,7 +292,7 @@ def candidate_space(bucket: ShapeBucket,
     cands = []
     for strategy in ("pallas_fused", "pallas"):
         for nt in (256, 512, 1024):
-            if nt > bucket.n and nt != min(256, bucket.n):
+            if nt > bucket.n and nt != 256:   # small buckets: one node tile
                 continue
             for eb in (256, 512, 1024):
                 for ft in (128, 256):
@@ -284,17 +302,17 @@ def candidate_space(bucket: ShapeBucket,
                         cfg = KernelConfig(strategy=strategy, node_tile=nt,
                                            edge_block=eb, feat_tile=ft,
                                            stream=stream)
-                        if cfg.edge_granule > bucket.e:
+                        if cfg.edge_granule > max(bucket.e, 256):
                             continue
                         if vmem_bytes(bucket, cfg) > VMEM_BUDGET:
                             continue
                         cands.append(cfg)
     if not cands:
-        # past the gather-column VMEM cliff (N·FT alone exceeds the
-        # budget, ~28k padded nodes — DESIGN.md §3/§14) no pallas point
-        # fits; the honest answer is the XLA lowering.
+        # layers so wide that even the smallest tiles' [FT, FO] weight
+        # block and [NT, FO] accumulator overflow VMEM: the XLA lowering
         return [KernelConfig(strategy="xla")]
-    return cands
+    default = KernelConfig(strategy="pallas_fused")
+    return sorted(cands, key=lambda c: c != default)     # stable
 
 
 def _measure(cfg: KernelConfig, bucket: ShapeBucket,

@@ -6,45 +6,46 @@ arc list. GPU implementations use shared-memory atomics; TPU has no scatter
 hardware, so we ADAPT (see DESIGN.md §3): the scatter becomes a **one-hot
 matmul** that feeds the MXU —
 
+    G   = h[src]                                      # XLA gather [E, F]
     for each node tile N_t, feature tile F_t, edge block E_b:
-        G   = h[src[E_b], F_t]                        # gather   [EB, FT]
         S   = onehot(dst[E_b] - N_t.start) * w[E_b]   # scatter  [NT, EB]
-        out[N_t, F_t] += S @ G                        # MXU      [NT, FT]
+        out[N_t, F_t] += S @ G[E_b, F_t]              # MXU      [NT, FT]
     after the last edge block:
         out[N_t, F_t] *= inv_scale[N_t, None]         # fused epilogue
 
+The row gather runs in XLA before the ``pallas_call``: Mosaic cannot lower
+an arbitrary row gather inside a kernel, so the kernel streams
+``[granule, FT]`` blocks of the pre-gathered rows like any other operand.
+Every operand block is 2-D and lane-dense: the arc arrays travel as
+``[1, E]`` rows and ``inv_scale`` as an ``[N, 1]`` column, because Mosaic
+and XLA tile 1-D arrays differently.
+
 Blocking: the grid is (node tiles × feature tiles × edge granules). The
-tile sizes are no longer fixed constants — they come from a
-:class:`repro.kernels.autotune.KernelConfig` (the module constants are the
-untuned PR 4 point and remain the default). Two perf refinements over the
-PR 4 kernel (DESIGN.md §14):
+tile sizes come from a :class:`repro.kernels.autotune.KernelConfig`
+(the module constants are the untuned default point). Two refinements
+(DESIGN.md §14):
 
 * **Degenerate-tile fast path.** ``edge_dst`` arrives sorted (the assemble
   layout), so most edge blocks touch one or two node tiles. The wrapper
-  precomputes each block's dst range ``[lo, hi]`` (two tiny int32 arrays,
-  passed through SMEM like ``flash_decode``'s length scalar) and the kernel
-  wraps the gather + one-hot matmul in ``pl.when(block ∩ tile ≠ ∅)`` — a
-  skipped block costs a scalar compare instead of an [NT, EB] × [EB, FT]
-  MXU pass. Weight-0 padding arcs can only *widen* a block's range, never
-  corrupt a result, so the contract below is unchanged.
+  precomputes each block's dst range ``[lo, hi]`` (two small int32 arrays
+  in SMEM) and the kernel wraps the one-hot matmul in
+  ``pl.when(block ∩ tile ≠ ∅)`` — a skipped block costs a scalar compare
+  instead of an [NT, EB] × [EB, FT] MXU pass. Weight-0 padding arcs can
+  only *widen* a block's range, never corrupt a result.
 
-* **Double-buffered edge streaming.** The edge BlockSpec loads
-  ``stream × edge_block`` arcs per grid step (one larger DMA granule that
-  Pallas pipelines against compute across grid steps), and the kernel
-  unrolls over the ``stream`` sub-blocks, each with its own skip guard —
-  bigger copies in flight, same per-matmul shapes.
+* **Streamed edge granules.** The edge BlockSpecs load
+  ``stream × edge_block`` arcs per grid step (one larger DMA that Pallas
+  pipelines against compute across grid steps), and the kernel unrolls
+  over the ``stream`` sub-blocks, each with its own skip guard.
 
-The VMEM working set per step is
-
-    (N·FT + 3·EB·S + NT·FT) · 4 B
-
-where only the gather operand ``h`` (one [N, FT] feature column) still
-scales with N; beyond N ≈ 28k padded nodes the gather operand itself would
-have to be streamed from HBM — the paper's partitioning keeps partitions
-far smaller (k scales with the graph). The output block index is
-independent of the edge-granule grid dimension, so Pallas keeps it resident
-and we accumulate across granules (init at granule 0, scale epilogue at the
-last). Accumulation is f32.
+The VMEM working set per step (:func:`repro.kernels.autotune.vmem_bytes`)
+is the double-buffered ``[granule, FT]`` row block, the arc rows, the
+``[NT, FT]`` output tile and the ``[NT, EB]`` one-hot: nothing in it
+scales with N. The output block index is independent of the edge-granule
+grid dimension, so Pallas keeps it resident and we accumulate across
+granules (init at granule 0, scale epilogue at the last). Accumulation is
+f32, and the matmuls run at ``Precision.HIGHEST`` so a TPU result matches
+the f32 segment-sum reference instead of a one-pass bf16 product.
 
 Differentiation (DESIGN.md §11): ``csr_aggregate_pallas`` carries a
 ``jax.custom_vjp``. With A the [N, N] weighted adjacency the forward is
@@ -57,7 +58,8 @@ Differentiation (DESIGN.md §11): ``csr_aggregate_pallas`` carries a
 * the edge-weight cotangent is the per-edge row dot
   ``dw[e] = inv_scale[dst[e]] · <g[dst[e]], h[src[e]]>`` — a small
   companion kernel (``_edge_dot_kernel``) that fuses the multiply-reduce
-  over feature tiles so the [E, F] products never hit HBM;
+  over feature tiles so the [E, F] products never hit HBM; its output is
+  one lane-dense ``[1, E]`` row;
 * ``inv_scale`` (the fused degree normalization) and the arc lists are
   graph *structure*, not trainable data: their cotangents are defined as
   zero (``float0`` for the int arrays).
@@ -73,11 +75,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .autotune import KernelConfig
+from .autotune import KernelConfig, interpret_mode
 
-# The untuned PR 4 tile point — kept as module constants for back-compat
-# and as the default KernelConfig; the autotuner supersedes them per
-# (backend, shape-bucket).
+# The untuned tile point — the default KernelConfig; the autotuner
+# supersedes it per (backend, shape-bucket).
 NODE_TILE = 512
 EDGE_BLOCK = 256
 FEAT_TILE = 128
@@ -138,53 +139,68 @@ def check_shape_contract(n: int, f: int, e: int, num_nodes: int,
 
 
 def edge_block_ranges(edge_dst: jnp.ndarray, edge_block: int):
-    """Per-edge-block dst range [lo, hi] (int32, [E/EB] each) feeding the
-    degenerate-tile fast path. Computed on the padded arc list; weight-0
-    padding arcs only widen a range — the skip is conservative."""
+    """Per-edge-block dst range [lo, hi] feeding the degenerate-tile fast
+    path. Computed on the padded arc list; weight-0 padding arcs only widen
+    a range — the skip is conservative.
+
+    Block ``b``'s range sits at ``[b // 128, b % 128]`` of two int32
+    ``[blocks / 128, 128]`` arrays (zero-padded; the kernel never reads
+    the padding). Under ``vmap`` the SMEM operand gains a leading
+    partition axis, and Mosaic only accepts its per-partition block when
+    the last two dims are whole — a flat ``[blocks]`` row is not."""
     blocks = edge_dst.astype(jnp.int32).reshape(-1, edge_block)
-    return jnp.min(blocks, axis=1), jnp.max(blocks, axis=1)
+    pad = (0, -blocks.shape[0] % 128)
+    return (jnp.pad(jnp.min(blocks, axis=1), pad).reshape(-1, 128),
+            jnp.pad(jnp.max(blocks, axis=1), pad).reshape(-1, 128))
 
 
-def _agg_kernel(lo_ref, hi_ref, src_ref, dst_ref, w_ref, inv_ref, h_ref,
-                out_ref, *, edge_block: int, stream: int):
+def accumulate_edge_granule(lo_ref, hi_ref, dst_ref, w_ref, rows_ref,
+                            acc_ref, *, edge_block: int, stream: int,
+                            granule_idx):
+    """``acc_ref[NT, FT] += onehot(dst) · w @ rows`` over one edge granule.
+
+    ``rows_ref`` holds the granule's pre-gathered source rows
+    ``[EB·S, FT]``; ``dst_ref``/``w_ref`` its ``[1, EB·S]`` arc rows. Each
+    of the ``stream`` sub-blocks is skipped when its dst range misses this
+    node tile. Shared by the aggregation and the fused-layer kernels."""
+    nt = acc_ref.shape[0]
+    tile_lo = pl.program_id(0) * nt
+    for s in range(stream):                  # unrolled sub-blocks
+        blk = granule_idx * stream + s
+        lo = lo_ref[blk // 128, blk % 128]
+        hi = hi_ref[blk // 128, blk % 128]
+
+        @pl.when(jnp.logical_and(hi >= tile_lo, lo < tile_lo + nt))
+        def _compute(s=s):
+            cols = slice(s * edge_block, (s + 1) * edge_block)
+            dst = dst_ref[:, cols]                         # [1, EB]
+            w = w_ref[:, cols].astype(jnp.float32)         # [1, EB]
+            rows = rows_ref[cols, :].astype(jnp.float32)   # [EB, FT]
+            # masked one-hot scatter for THIS node tile:
+            # S[i, e] = w[e] * (dst[e] == tile_start + i)  -> [NT, EB]
+            ids = (jax.lax.broadcasted_iota(jnp.int32, (nt, edge_block), 0)
+                   + tile_lo)
+            scatter = jnp.where(ids == dst, w, 0.0)
+            acc_ref[...] += jax.lax.dot(
+                scatter, rows, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+
+def _agg_kernel(lo_ref, hi_ref, dst_ref, w_ref, inv_ref, rows_ref, out_ref,
+                *, edge_block: int, stream: int):
     sb = pl.program_id(2)
 
     @pl.when(sb == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src_all = src_ref[...]                   # [EB*S] int32
-    dst_all = dst_ref[...]                   # [EB*S] int32
-    w_all = w_ref[...].astype(jnp.float32)   # [EB*S]
-    h = h_ref[...]                           # [N, FT] full gather column
-    nt = out_ref.shape[0]
-    tile_lo = pl.program_id(0) * nt
-
-    for s in range(stream):                  # unrolled sub-blocks
-        blk = sb * stream + s
-        lo = lo_ref[blk]
-        hi = hi_ref[blk]
-
-        # degenerate-tile fast path: skip the gather + one-hot matmul when
-        # this sub-block's dst range misses the node tile entirely
-        @pl.when(jnp.logical_and(hi >= tile_lo, lo < tile_lo + nt))
-        def _compute(s=s):
-            src = src_all[s * edge_block:(s + 1) * edge_block]
-            dst = dst_all[s * edge_block:(s + 1) * edge_block]
-            w = w_all[s * edge_block:(s + 1) * edge_block]
-            # gather source rows: [EB, FT]
-            gathered = jnp.take(h, src, axis=0).astype(jnp.float32)
-            # masked one-hot scatter for THIS node tile:
-            # S[i, e] = w[e] * (dst[e] == tile_start + i)  -> [NT, EB]
-            rows = (jax.lax.broadcasted_iota(jnp.int32, (nt, edge_block), 0)
-                    + tile_lo)
-            scatter = jnp.where(rows == dst[None, :], w[None, :], 0.0)
-            out_ref[...] += jax.lax.dot(scatter, gathered,
-                                        preferred_element_type=jnp.float32)
+    accumulate_edge_granule(lo_ref, hi_ref, dst_ref, w_ref, rows_ref,
+                            out_ref, edge_block=edge_block, stream=stream,
+                            granule_idx=sb)
 
     @pl.when(sb == pl.num_programs(2) - 1)
     def _epilogue():
-        out_ref[...] = out_ref[...] * inv_ref[...].astype(jnp.float32)[:, None]
+        out_ref[...] = out_ref[...] * inv_ref[...]       # [NT, 1] column
 
 
 def _edge_dot_kernel(a_ref, b_ref, out_ref):
@@ -194,12 +210,21 @@ def _edge_dot_kernel(a_ref, b_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.sum(a_ref[...].astype(jnp.float32)
-                            * b_ref[...].astype(jnp.float32), axis=1)
+    prod = a_ref[...].astype(jnp.float32) * b_ref[...].astype(jnp.float32)
+    # reduce over features along sublanes so the [1, EB] result is a
+    # lane-dense row (an [EB] vector has no layout Mosaic and XLA share)
+    out_ref[...] += jnp.sum(prod.T, axis=0, keepdims=True)
 
 
 def _node_tile(n: int, node_tile: int) -> int:
     return n if n <= node_tile else node_tile
+
+
+def edge_row_specs(granule: int):
+    """BlockSpecs of the ``[1, E]`` dst and weight rows, one granule per
+    step of the grid's last (edge) dimension."""
+    spec = pl.BlockSpec((1, granule), lambda i, ft, sb: (0, sb))
+    return [spec, spec]
 
 
 def _aggregate(h, edge_src, edge_dst, edge_weight, inv_scale, *,
@@ -213,22 +238,22 @@ def _aggregate(h, edge_src, edge_dst, edge_weight, inv_scale, *,
     granule = eb * stream
     grid = (n // nt, f // ft_sz, e // granule)
     lo, hi = edge_block_ranges(edge_dst, eb)
+    rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
     out = pl.pallas_call(
         functools.partial(_agg_kernel, edge_block=eb, stream=stream),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
             pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((nt,), lambda i, ft, sb: (i,)),
-            pl.BlockSpec((n, ft_sz), lambda i, ft, sb: (0, ft)),
+            *edge_row_specs(granule),                 # dst, w
+            pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
+            pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
         ],
         out_specs=pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
         out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
         interpret=interpret,
-    )(lo, hi, edge_src, edge_dst, edge_weight, inv_scale, h)
+    )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
+      inv_scale.reshape(n, 1), rows)
     return out.astype(h.dtype)
 
 
@@ -237,17 +262,18 @@ def _edge_dot(a, b, *, interpret: bool, config: KernelConfig) -> jnp.ndarray:
     e, f = a.shape
     eb, ft_sz = config.edge_block, min(config.feat_tile, f)
     grid = (e // eb, f // ft_sz)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _edge_dot_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((eb, ft_sz), lambda i, ft: (i, ft)),
             pl.BlockSpec((eb, ft_sz), lambda i, ft: (i, ft)),
         ],
-        out_specs=pl.BlockSpec((eb,), lambda i, ft: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.float32),
+        out_specs=pl.BlockSpec((1, eb), lambda i, ft: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, e), jnp.float32),
         interpret=interpret,
     )(a, b)
+    return out.reshape(e)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -291,11 +317,10 @@ def _aggregate_diff_bwd(interpret, config, res, g):
 _aggregate_diff.defvjp(_aggregate_diff_fwd, _aggregate_diff_bwd)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_nodes", "interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("num_nodes", "config"))
 def csr_aggregate_pallas(h: jnp.ndarray, edge_src: jnp.ndarray,
                          edge_dst: jnp.ndarray, edge_weight: jnp.ndarray,
-                         num_nodes: int, interpret: bool = True,
+                         num_nodes: int,
                          inv_scale: jnp.ndarray | None = None,
                          src_perm: jnp.ndarray | None = None,
                          config: KernelConfig | None = None
@@ -309,7 +334,7 @@ def csr_aggregate_pallas(h: jnp.ndarray, edge_src: jnp.ndarray,
     graph structure (zero cotangent). ``src_perm`` (default
     ``argsort(edge_src)``, dead-code-eliminated unless differentiated)
     orders the reversed arc list for the transpose pass of the VJP.
-    ``config`` (default: the fixed PR 4 tile point) selects the tuned tile
+    ``config`` (default: the untuned tile point) selects the tuned tile
     sizes and stream factor — resolve one with
     :func:`repro.kernels.autotune.get_config`.
 
@@ -328,6 +353,6 @@ def csr_aggregate_pallas(h: jnp.ndarray, edge_src: jnp.ndarray,
         inv_scale = jnp.ones((n,), jnp.float32)
     if src_perm is None:
         src_perm = jnp.argsort(edge_src)
-    return _aggregate_diff(interpret, config, h, edge_src, edge_dst,
+    return _aggregate_diff(interpret_mode(), config, h, edge_src, edge_dst,
                            edge_weight, inv_scale.astype(jnp.float32),
                            src_perm)

@@ -12,12 +12,12 @@ MXU matmuls: logits = q_g @ k_blk^T  [G, SB]  and  acc += p @ v_blk  [G, D].
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .autotune import interpret_mode
 
 SEQ_BLOCK = 512
 LANES = 128
@@ -64,10 +64,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref):
         out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def flash_decode_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        length: jnp.ndarray, interpret: bool = True
-                        ) -> jnp.ndarray:
+                        length: jnp.ndarray) -> jnp.ndarray:
     """q: [H, D]; k, v: [S, Hkv, D]; length: scalar. Returns [H, D] f32->q.dtype.
 
     Matches :func:`repro.kernels.ref.flash_decode_ref` (scale 1/sqrt(D))."""
@@ -100,6 +99,6 @@ def flash_decode_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(length, qg, kt, vt)
     return out.reshape(hq, d).astype(q.dtype)

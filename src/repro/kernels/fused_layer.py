@@ -1,9 +1,9 @@
 """Pallas TPU kernel: one fused GNN layer — aggregate + dense + bias + relu
 in a single ``pallas_call``, with a custom VJP so it is a real training path.
 
-Why fuse (DESIGN.md §14): the PR 4 kernel computes the aggregate, writes it
-to HBM, and XLA then reads it back for the dense transform — one full
-[N, F] round trip plus a second kernel launch per layer. This kernel keeps
+Why fuse (DESIGN.md §14): the aggregation kernel computes the aggregate,
+writes it to HBM, and XLA then reads it back for the dense transform — one
+full [N, F] round trip plus a second kernel launch per layer. This kernel keeps
 the aggregate tile in VMEM and runs the dense epilogue on it while it is
 still resident, following the fused-epilogue idiom of
 ``kernels/flash_decode.py`` (accumulator scratch + ``pl.when`` init/finish
@@ -19,9 +19,10 @@ on the streaming grid dimension):
 semantics), so the dense transform is accumulated feature-tile by
 feature-tile without the aggregate ever leaving VMEM. The aggregate is
 *also* written out — the backward pass needs it for dW, and XLA
-dead-code-eliminates the store on forward-only calls. The edge streaming
-and the degenerate-tile skip are shared with
-:mod:`repro.kernels.csr_aggregate` (same SMEM lo/hi fast path).
+dead-code-eliminates the store on forward-only calls. The XLA row gather
+before the call, the edge streaming and the degenerate-tile skip are shared
+with :mod:`repro.kernels.csr_aggregate` (same SMEM lo/hi fast path, same
+2-D operand blocks); the bias travels as a ``[1, FO]`` row.
 
 Backward: with A the weighted adjacency, ``agg = diag(inv)·A·h``,
 ``z = agg@W + b``, ``out = act(z)``:
@@ -33,8 +34,9 @@ Backward: with A the weighted adjacency, ``agg = diag(inv)·A·h``,
     dh  = Aᵀ·diag(inv)·da         — the transpose-aggregation kernel
     dw[e] = inv[dst[e]]·<da[dst[e]], h[src[e]]>  — the edge-dot kernel
 
-i.e. the reverse pass reuses the PR 4 kernels (`_aggregate`, `_edge_dot`)
-with the same KernelConfig, so tuned tiles apply to both directions.
+i.e. the reverse pass reuses the aggregation kernels (`_aggregate`,
+`_edge_dot`) with the same KernelConfig, so tuned tiles apply to both
+directions.
 
 :func:`fused_gcn_reference` is the jnp composition of the same math — the
 parity oracle in tests AND the ``"xla"`` strategy the autotuner picks on
@@ -51,10 +53,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .autotune import KernelConfig
+from .autotune import KernelConfig, interpret_mode
 from .csr_aggregate import (DEFAULT_CONFIG, ShapeContractError, _aggregate,
-                            _edge_dot, _node_tile, check_shape_contract,
-                            edge_block_ranges)
+                            _edge_dot, _node_tile, accumulate_edge_granule,
+                            check_shape_contract, edge_block_ranges,
+                            edge_row_specs)
 
 LANES = 128
 
@@ -76,7 +79,7 @@ def fused_gcn_reference(h, edge_src, edge_dst, edge_weight, inv_scale,
     return out.astype(h.dtype)
 
 
-def _fused_kernel(lo_ref, hi_ref, src_ref, dst_ref, w_ref, inv_ref, h_ref,
+def _fused_kernel(lo_ref, hi_ref, dst_ref, w_ref, inv_ref, rows_ref,
                   wmat_ref, b_ref, agg_ref, out_ref, zacc_ref, *,
                   edge_block: int, stream: int, activate: bool):
     ftid = pl.program_id(1)
@@ -88,37 +91,16 @@ def _fused_kernel(lo_ref, hi_ref, src_ref, dst_ref, w_ref, inv_ref, h_ref,
     def _init():
         agg_ref[...] = jnp.zeros_like(agg_ref)
 
-    src_all = src_ref[...]
-    dst_all = dst_ref[...]
-    w_all = w_ref[...].astype(jnp.float32)
-    h = h_ref[...]
-    nt = agg_ref.shape[0]
-    tile_lo = pl.program_id(0) * nt
-
-    for s in range(stream):                  # unrolled streamed sub-blocks
-        blk = sb * stream + s
-        lo = lo_ref[blk]
-        hi = hi_ref[blk]
-
-        @pl.when(jnp.logical_and(hi >= tile_lo, lo < tile_lo + nt))
-        def _compute(s=s):
-            src = src_all[s * edge_block:(s + 1) * edge_block]
-            dst = dst_all[s * edge_block:(s + 1) * edge_block]
-            w = w_all[s * edge_block:(s + 1) * edge_block]
-            gathered = jnp.take(h, src, axis=0).astype(jnp.float32)
-            rows = (jax.lax.broadcasted_iota(jnp.int32, (nt, edge_block), 0)
-                    + tile_lo)
-            scatter = jnp.where(rows == dst[None, :], w[None, :], 0.0)
-            agg_ref[...] += jax.lax.dot(scatter, gathered,
-                                        preferred_element_type=jnp.float32)
+    accumulate_edge_granule(lo_ref, hi_ref, dst_ref, w_ref, rows_ref,
+                            agg_ref, edge_block=edge_block, stream=stream,
+                            granule_idx=sb)
 
     # fused epilogue: normalization, then the dense transform on the still-
     # resident aggregate tile (zacc accumulates over feature tiles), then
     # bias + activation once the last feature tile lands.
     @pl.when(last_sb)
     def _normalize():
-        agg_ref[...] = (agg_ref[...]
-                        * inv_ref[...].astype(jnp.float32)[:, None])
+        agg_ref[...] = agg_ref[...] * inv_ref[...]       # [NT, 1] column
 
     @pl.when(jnp.logical_and(last_sb, ftid == 0))
     def _zacc_init():
@@ -128,11 +110,12 @@ def _fused_kernel(lo_ref, hi_ref, src_ref, dst_ref, w_ref, inv_ref, h_ref,
     def _dense():
         zacc_ref[...] += jax.lax.dot(
             agg_ref[...], wmat_ref[...].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(last_sb, ftid == num_ft - 1))
     def _finish():
-        z = zacc_ref[...] + b_ref[...].astype(jnp.float32)[None, :]
+        z = zacc_ref[...] + b_ref[...].astype(jnp.float32)   # [1, FO] row
         out_ref[...] = jnp.maximum(z, 0.0) if activate else z
 
 
@@ -148,6 +131,7 @@ def _fused_forward(h, edge_src, edge_dst, edge_weight, inv_scale, wmat, b,
     granule = eb * stream
     grid = (n // nt, f // ft_sz, e // granule)
     lo, hi = edge_block_ranges(edge_dst, eb)
+    rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
     agg, out = pl.pallas_call(
         functools.partial(_fused_kernel, edge_block=eb, stream=stream,
                           activate=activate),
@@ -155,13 +139,11 @@ def _fused_forward(h, edge_src, edge_dst, edge_weight, inv_scale, wmat, b,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
             pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((granule,), lambda i, ft, sb: (sb,)),
-            pl.BlockSpec((nt,), lambda i, ft, sb: (i,)),
-            pl.BlockSpec((n, ft_sz), lambda i, ft, sb: (0, ft)),
+            *edge_row_specs(granule),                 # dst, w
+            pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
+            pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
             pl.BlockSpec((ft_sz, fo), lambda i, ft, sb: (ft, 0)),
-            pl.BlockSpec((fo,), lambda i, ft, sb: (0,)),
+            pl.BlockSpec((1, fo), lambda i, ft, sb: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
@@ -173,7 +155,8 @@ def _fused_forward(h, edge_src, edge_dst, edge_weight, inv_scale, wmat, b,
         ],
         scratch_shapes=[pltpu.VMEM((nt, fo), jnp.float32)],
         interpret=interpret,
-    )(lo, hi, edge_src, edge_dst, edge_weight, inv_scale, h, wmat, b)
+    )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
+      inv_scale.reshape(n, 1), rows, wmat, b.reshape(1, fo))
     return out, agg
 
 
@@ -202,11 +185,14 @@ def _fused_diff_bwd(interpret, activate, config, res, g):
     if activate:
         gz = gz * (out > 0.0)
     db = jnp.sum(gz, axis=0)
-    dwmat = agg.T @ gz                                   # [F, FO]
-    da = gz @ wmat.astype(jnp.float32).T                 # [N, F]
+    # f32 products like the forward kernel's (a TPU's default is one bf16
+    # pass, ~1e-3 off the f32 reference gradients)
+    mm = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    dwmat = mm(agg.T, gz)                                # [F, FO]
+    da = mm(gz, wmat.astype(jnp.float32).T)              # [N, F]
     ones = jnp.ones((h.shape[0],), jnp.float32)
     # dh: transpose aggregation over the reversed src-sorted arc list,
-    # normalization folded into the reverse weights (PR 4 kernel, same cfg).
+    # normalization folded into the reverse weights (same kernel and cfg).
     rev_w = jnp.take(w.astype(jnp.float32) * jnp.take(inv, dst), perm)
     dh = _aggregate(da, jnp.take(dst, perm), jnp.take(src, perm), rev_w,
                     ones, interpret=interpret, config=config).astype(h.dtype)
@@ -223,11 +209,11 @@ _fused_diff.defvjp(_fused_diff_fwd, _fused_diff_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("num_nodes", "activate",
-                                             "interpret", "config"))
+                                             "config"))
 def fused_gcn_pallas(h: jnp.ndarray, edge_src: jnp.ndarray,
                      edge_dst: jnp.ndarray, edge_weight: jnp.ndarray,
                      num_nodes: int, wmat: jnp.ndarray, b: jnp.ndarray,
-                     activate: bool = True, interpret: bool = True,
+                     activate: bool = True,
                      inv_scale: jnp.ndarray | None = None,
                      src_perm: jnp.ndarray | None = None,
                      config: KernelConfig | None = None) -> jnp.ndarray:
@@ -253,6 +239,6 @@ def fused_gcn_pallas(h: jnp.ndarray, edge_src: jnp.ndarray,
         inv_scale = jnp.ones((n,), jnp.float32)
     if src_perm is None:
         src_perm = jnp.argsort(edge_src)
-    return _fused_diff(interpret, activate, config, h, edge_src, edge_dst,
-                       edge_weight, inv_scale.astype(jnp.float32), wmat, b,
-                       src_perm)
+    return _fused_diff(interpret_mode(), activate, config, h, edge_src,
+                       edge_dst, edge_weight, inv_scale.astype(jnp.float32),
+                       wmat, b, src_perm)

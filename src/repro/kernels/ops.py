@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels (padding + dispatch).
 
-On CPU (this container) the kernels execute in interpret mode; on TPU set
-``interpret=False`` (the default flips automatically based on the backend).
+The kernels compile through Mosaic on a TPU and run in the Pallas
+interpreter on every other backend; the backend alone decides
+(:func:`repro.kernels.autotune.interpret_mode`), so no caller can send a TPU
+run through the interpreter.
 
 **Padding contract** (the single contract for every aggregation path — the
 jnp segment-sum in :mod:`repro.gnn.layers`, the oracle in
@@ -30,10 +32,6 @@ from .csr_aggregate import (DEFAULT_CONFIG, EDGE_BLOCK, FEAT_TILE, NODE_TILE,
                             csr_aggregate_pallas)
 from .flash_decode import flash_decode_pallas
 from .fused_layer import LANES, fused_gcn_pallas, fused_gcn_reference
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int, value=0) -> jnp.ndarray:
@@ -66,11 +64,10 @@ def _pad_graph(h, edge_src, edge_dst, edge_weight, inv_scale,
     return hp, es, ed, ew, inv, n_pad
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_nodes", "interpret", "config"))
+@functools.partial(jax.jit, static_argnames=("num_nodes", "config"))
 def csr_aggregate(h: jnp.ndarray, edge_src: jnp.ndarray,
                   edge_dst: jnp.ndarray, edge_weight: jnp.ndarray,
-                  num_nodes: int, interpret: bool | None = None,
+                  num_nodes: int,
                   inv_scale: jnp.ndarray | None = None,
                   config: KernelConfig | None = None) -> jnp.ndarray:
     """Weighted neighbor-sum via the Pallas kernel, with automatic padding.
@@ -86,13 +83,11 @@ def csr_aggregate(h: jnp.ndarray, edge_src: jnp.ndarray,
     dead-code-eliminated by XLA on non-differentiated calls). ``inv_scale``
     and the arc lists are graph structure: zero cotangent by design.
 
-    ``config`` picks the tuned tile sizes/stream factor (default: the fixed
-    PR 4 point); its *strategy* field is ignored here — this wrapper is
+    ``config`` picks the tuned tile sizes/stream factor (default: the
+    untuned point); its *strategy* field is ignored here — this wrapper is
     always the Pallas aggregation (strategy dispatch happens one level up,
     in :func:`repro.gnn.layers.aggregate_mean` / :func:`fused_gcn_layer`).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     if config is None:
         config = DEFAULT_CONFIG
     n, f = h.shape
@@ -100,17 +95,15 @@ def csr_aggregate(h: jnp.ndarray, edge_src: jnp.ndarray,
         h, edge_src, edge_dst, edge_weight, inv_scale, config)
     perm = jnp.argsort(es)           # bwd-only; DCE'd on forward-only calls
     out = csr_aggregate_pallas(hp, es, ed, ew, num_nodes=n_pad,
-                               interpret=interpret, inv_scale=inv,
-                               src_perm=perm, config=config)
+                               inv_scale=inv, src_perm=perm, config=config)
     return out[:n, :f].astype(h.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("activate", "interpret",
-                                             "config"))
+@functools.partial(jax.jit, static_argnames=("activate", "config"))
 def fused_gcn_layer(h: jnp.ndarray, edge_src: jnp.ndarray,
                     edge_dst: jnp.ndarray, edge_weight: jnp.ndarray,
                     in_degree: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
-                    activate: bool = True, interpret: bool | None = None,
+                    activate: bool = True,
                     config: KernelConfig | None = None) -> jnp.ndarray:
     """One fused GNN layer: ``act(mean-aggregate(h) @ w + b)``.
 
@@ -120,7 +113,7 @@ def fused_gcn_layer(h: jnp.ndarray, edge_src: jnp.ndarray,
     - ``"pallas_fused"``: one ``pallas_call`` for the whole layer
       (:func:`repro.kernels.fused_layer.fused_gcn_pallas`), padding
       handled here;
-    - ``"pallas"``: the PR 4 aggregation kernel with tuned tiles + an XLA
+    - ``"pallas"``: the aggregation kernel with tuned tiles + an XLA
       dense epilogue;
     - ``"xla"``: the jnp composition under this jit (the right answer
       wherever Pallas would run in interpret mode).
@@ -129,8 +122,6 @@ def fused_gcn_layer(h: jnp.ndarray, edge_src: jnp.ndarray,
     strategy; parity across strategies is pinned in
     ``tests/test_fused_layer.py``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     if config is None:
         config = DEFAULT_CONFIG
     inv = 1.0 / jnp.maximum(in_degree.astype(jnp.float32), 1.0)
@@ -139,9 +130,12 @@ def fused_gcn_layer(h: jnp.ndarray, edge_src: jnp.ndarray,
                                    w, b, activate=activate)
     if config.strategy == "pallas":
         agg = csr_aggregate(h, edge_src, edge_dst, edge_weight,
-                            num_nodes=h.shape[0], interpret=interpret,
-                            inv_scale=inv, config=config)
-        z = (agg.astype(jnp.float32) @ w.astype(jnp.float32)
+                            num_nodes=h.shape[0], inv_scale=inv,
+                            config=config)
+        # f32 like the kernel's own products (one bf16 pass on a TPU
+        # otherwise)
+        z = (jnp.dot(agg.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
              + b.astype(jnp.float32)[None, :])
         # jax.nn.relu for the gradient-at-zero convention (see fused_layer)
         out = jax.nn.relu(z) if activate else z
@@ -155,16 +149,13 @@ def fused_gcn_layer(h: jnp.ndarray, edge_src: jnp.ndarray,
     bp = _pad_to(b, LANES, 0)
     perm = jnp.argsort(es)
     out = fused_gcn_pallas(hp, es, ed, ew, num_nodes=n_pad, wmat=wp, b=bp,
-                           activate=activate, interpret=interpret,
-                           inv_scale=invp, src_perm=perm, config=config)
+                           activate=activate, inv_scale=invp,
+                           src_perm=perm, config=config)
     return out[:n, :fo].astype(h.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                 length: jnp.ndarray, interpret: bool | None = None
-                 ) -> jnp.ndarray:
+                 length: jnp.ndarray) -> jnp.ndarray:
     """Single-token GQA decode attention. q: [H, D]; k/v: [S, Hkv, D]."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    return flash_decode_pallas(q, k, v, length, interpret=interpret)
+    return flash_decode_pallas(q, k, v, length)

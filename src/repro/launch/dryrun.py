@@ -246,7 +246,7 @@ def run_gnn_dryrun(multi_pod: bool, out_dir: str) -> dict:
                            stale_bytes_per_epoch)
     from repro.launch.hlo_analysis import (collective_bytes,
                                            normalize_cost_analysis)
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.optim import adamw_init
 
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -316,7 +316,7 @@ def run_gnn_dryrun(multi_pod: bool, out_dir: str) -> dict:
     # --- synchronized halo-exchange baseline (single-axis mesh only: the
     # shard_map step uses a flat "data" axis) ---------------------------------
     if not multi_pod:
-        sync_mesh = jax.make_mesh((k,), ("data",))
+        sync_mesh = make_mesh((k,), ("data",))
         with sync_mesh:
             sync = make_sync_train_step(cfg, halo, False, sync_mesh, 1e-2)
             sync_compiled = sync.lower(p_sds, o_sds, tensors_sds,

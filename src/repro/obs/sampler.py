@@ -7,12 +7,13 @@ Two memory sources feed gauges in the registry:
   PR 9's out-of-core work gates on, so the pipeline samples it after every
   stage into ``process.peak_rss_bytes``.
 * JAX device memory — ``device.memory_stats()`` where the backend exposes
-  it (TPU/GPU do; CPU returns None). Sampled into
-  ``jax.device.bytes_in_use`` / ``jax.device.peak_bytes_in_use``.
+  it (TPU/GPU do; CPU returns None), the maximum over this process's
+  local devices so a run over four chips reports its fullest chip.
+  Sampled into ``jax.device.bytes_in_use`` / ``jax.device.peak_bytes_in_use``.
 
-Everything JAX-touching imports lazily and fails soft: ``repro.obs`` must
-stay importable (and fast) in processes that never load JAX, e.g. the
-``summarize`` CLI reading a trace file.
+Everything JAX-touching imports lazily: ``repro.obs`` must stay importable
+(and fast) in processes that never load JAX, e.g. the ``summarize`` CLI
+reading a trace file.
 """
 from __future__ import annotations
 
@@ -35,11 +36,15 @@ def peak_rss_bytes() -> Optional[int]:
 
 
 def _device_memory() -> Optional[dict]:
+    """Each ``memory_stats()`` key's maximum over the local devices."""
     try:
         import jax
-        stats = jax.devices()[0].memory_stats()
-    except Exception:
+    except ImportError:
         return None
+    stats: dict = {}
+    for device in jax.local_devices():
+        for key, value in (device.memory_stats() or {}).items():
+            stats[key] = max(stats.get(key, value), value)
     return stats or None
 
 
@@ -56,12 +61,13 @@ def sample_memory(registry) -> None:
 
 
 class jax_profiler_session:
-    """Context manager starting a ``jax.profiler`` trace for its body.
+    """Context manager running a ``jax.profiler`` trace over its body.
 
     Used around the training stage when the pipeline is given a profile
-    directory (``--jax-profile DIR``). Fails soft: if the profiler can't
-    start (backend without support, double-start), the body still runs and
-    the failure is recorded as a ``jax.profiler.failed`` counter.
+    directory (``--jax-profile DIR``). A profile that was asked for and
+    cannot start or stop is an error, not a run without a trace: the
+    failure is counted as ``jax.profiler.failed`` and raised (on stop,
+    unless the body is already raising).
     """
 
     def __init__(self, out_dir: Optional[str], registry=None):
@@ -69,24 +75,30 @@ class jax_profiler_session:
         self._registry = registry
         self._active = False
 
+    def _failed(self) -> None:
+        if self._registry is not None:
+            self._registry.counter("jax.profiler.failed").inc()
+
     def __enter__(self):
         if not self.out_dir:
             return self
+        import jax
         try:
-            import jax
             jax.profiler.start_trace(self.out_dir)
-            self._active = True
-        except Exception:
-            if self._registry is not None:
-                self._registry.counter("jax.profiler.failed").inc()
+        except RuntimeError:
+            self._failed()
+            raise
+        self._active = True
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._active:
+            self._active = False
+            import jax
             try:
-                import jax
                 jax.profiler.stop_trace()
-            except Exception:
-                if self._registry is not None:
-                    self._registry.counter("jax.profiler.failed").inc()
+            except RuntimeError:
+                self._failed()
+                if exc_type is None:
+                    raise
         return False

@@ -26,6 +26,8 @@ import os
 import sys
 from typing import List, Optional
 
+from repro.launch.compile_cache import enable_compile_cache
+
 DEFAULT_CACHE = os.path.join("~", ".cache", "repro", "partitions")
 
 
@@ -256,6 +258,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     logging.basicConfig(level=logging.INFO,
                         format="%(levelname)s %(name)s: %(message)s")
     args = _build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.cmd == "run":
         return _cmd_run(args)
     if args.cmd == "partitioners":

@@ -163,11 +163,11 @@ class PipelineReport:
                      f"n_pad={self.shapes['n_pad']} "
                      f"e_pad={self.shapes['e_pad']} [cache {bhit}]")
         agg = "jnp"
-        if c.get("use_kernel"):
-            strategies = sorted({v["strategy"]
-                                 for v in (self.kernel or {}).values()})
-            agg = "kernel[" + ",".join(strategies) + "]" if strategies \
-                else "pallas-kernel"
+        if self.kernel:
+            # the strategy resolved per layer-input width, named as it is
+            # (an "xla" resolution is not a kernel run)
+            agg = ",".join(f"{width}:{entry['strategy']}"
+                           for width, entry in sorted(self.kernel.items()))
         mode = c["mode"]
         if mode == "stale":
             period = c.get("sync_period", 0)

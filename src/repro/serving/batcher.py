@@ -82,36 +82,21 @@ class CompileLog:
     """Measured compile counts per jitted callable, split warmup/steady.
 
     Reads each function's jit cache size around the call (``_cache_size``),
-    falling back to a seen-shape set when the private API is unavailable —
-    either way the count reflects what XLA actually compiled."""
+    so the count is what XLA actually compiled."""
 
     def __init__(self):
         self.warm_compiles: Dict[str, int] = {}
         self.steady_compiles: Dict[str, int] = {}
         self._steady = False
-        self._shapes: Dict[str, set] = {}
 
     def mark_steady(self) -> None:
         """End of warmup: every compile from here on is a violation."""
         self._steady = True
 
-    def _cache_size(self, fn) -> Optional[int]:
-        try:
-            return fn._cache_size()
-        except AttributeError:
-            return None
-
     def call(self, name: str, fn: Callable, *args, **kwargs):
-        before = self._cache_size(fn)
+        before = fn._cache_size()
         out = fn(*args, **kwargs)
-        after = self._cache_size(fn)
-        if before is not None and after is not None:
-            compiled = after - before
-        else:   # fallback: infer from the argument shapes
-            shapes = tuple(getattr(a, "shape", None) for a in args)
-            seen = self._shapes.setdefault(name, set())
-            compiled = 0 if shapes in seen else 1
-            seen.add(shapes)
+        compiled = fn._cache_size() - before
         if compiled:
             book = (self.steady_compiles if self._steady
                     else self.warm_compiles)

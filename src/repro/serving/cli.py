@@ -33,6 +33,8 @@ import threading
 import time
 from typing import List, Optional
 
+from repro.launch.compile_cache import enable_compile_cache
+
 log = logging.getLogger("repro.serving")
 
 DEFAULT_BUNDLE_DIR = os.path.join("~", ".cache", "repro", "serving")
@@ -383,6 +385,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if not argv:
         argv = ["replay"]        # `python -m repro.serving` end-to-end
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.cmd == "replay":
         return cmd_replay(args)
     if args.cmd == "serve":

@@ -122,7 +122,7 @@ print("INERT_AT_ZERO:", bool(jnp.abs(za - zb).max() == 0.0))
 
 def test_sync_step_trains_through_pallas_kernel():
     """use_kernel=True is a real path in sync mode too: the shard_map step
-    (check_rep=False — pallas_call has no replication rule) lowers, still
+    (check_vma=False — pallas_call has no varying-axes rule) lowers, still
     contains the halo all_gather, and at dropout=0 matches the jnp path's
     loss."""
     out = run_with_devices(PREAMBLE + """

@@ -11,6 +11,7 @@ contract error; (6) the VMEM-filtered candidate space.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -23,8 +24,9 @@ import pytest
 from repro.kernels import fused_gcn_layer
 from repro.kernels.autotune import (VMEM_BUDGET, KernelConfig, ShapeBucket,
                                     autotune, candidate_space,
-                                    clear_memory_cache, get_config, override,
-                                    shape_bucket, vmem_bytes)
+                                    clear_memory_cache, fallback_config,
+                                    get_config, override, shape_bucket,
+                                    vmem_bytes)
 from repro.kernels.csr_aggregate import (ShapeContractError,
                                          csr_aggregate_pallas)
 from repro.kernels.fused_layer import fused_gcn_reference
@@ -233,10 +235,65 @@ def test_candidate_space_respects_vmem_budget():
 
 
 def test_candidate_space_past_gather_cliff_falls_back_to_xla():
-    # N·FT alone blows the budget past ~28k padded nodes (DESIGN.md §14).
-    bucket = ShapeBucket(n=1 << 20, e=1 << 22, f=128)
-    cands = candidate_space(bucket, backend="tpu")
-    assert [c.strategy for c in cands] == ["xla"]
+    # The source rows are gathered by XLA before the kernel, so no VMEM term
+    # grows with N: a 1M-node bucket keeps its Pallas candidates. Only a
+    # layer too wide for the smallest [FT, FO] weight block and [NT, FO]
+    # accumulator falls back to XLA (DESIGN.md §14).
+    big = candidate_space(ShapeBucket(n=1 << 20, e=1 << 22, f=128),
+                          backend="tpu")
+    assert big and all(c.uses_pallas for c in big)
+    assert big == candidate_space(ShapeBucket(n=1 << 13, e=1 << 22, f=128),
+                                  backend="tpu")
+    wide = candidate_space(ShapeBucket(n=1024, e=4096, f=8192),
+                           backend="tpu")
+    assert [c.strategy for c in wide] == ["xla"]
+
+
+@pytest.mark.parametrize("bucket", [
+    ShapeBucket(8, 128, 128), ShapeBucket(128, 1024, 128),
+    ShapeBucket(131072, 524288, 128), ShapeBucket(1 << 20, 1 << 22, 256),
+    ShapeBucket(1024, 4096, 2048), ShapeBucket(1024, 4096, 8192),
+])
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_fallback_config_is_a_candidate_the_filter_accepts(bucket, backend):
+    """The untuned path resolves to a config the tuner would accept: on
+    TPU a Pallas tiling under the VMEM budget (or XLA when none fits),
+    elsewhere XLA — never the interpreter."""
+    cfg = fallback_config(bucket, backend)
+    assert cfg in candidate_space(bucket, backend)
+    if cfg.uses_pallas:
+        assert backend == "tpu"
+        assert vmem_bytes(bucket, cfg) <= VMEM_BUDGET
+
+
+def test_factory_defaults_are_candidates_of_their_bucket():
+    """Every packaged TPU entry passes the same filter as a tuned one."""
+    from repro.kernels.autotune import _DEFAULTS_PATH, _configs_from_file
+    entries = _configs_from_file(_DEFAULTS_PATH)
+    assert entries
+    for (backend, key), cfg in entries.items():
+        n, e, f = (int(part[1:]) for part in key.split("_"))
+        assert cfg in candidate_space(ShapeBucket(n, e, f), backend), key
+
+
+def test_tpu_backend_never_selects_the_interpreter(monkeypatch):
+    """With the backend reporting a TPU, every pallas_call the layer traces
+    is compiled (interpret=False); nothing a caller passes can change it."""
+    h, src, dst, w_edge, deg, w, b = _star_graph(3, 100, 24, 700, 16)
+    # the mode is fixed when a jitted wrapper is traced: drop the traces
+    # other tests made on the CPU, and the TPU ones made here afterwards
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        for strategy in ("pallas_fused", "pallas"):
+            cfg = KernelConfig(strategy=strategy)
+            jaxpr = str(jax.make_jaxpr(jax.grad(lambda h: fused_gcn_layer(
+                h, src, dst, w_edge, deg, w, b, config=cfg).sum()))(h))
+            flags = re.findall(r"interpret=(\w+)", jaxpr)
+            assert len(flags) == jaxpr.count("pallas_call") == 3, strategy
+            assert set(flags) == {"False"}, (strategy, flags)
+    finally:
+        jax.clear_caches()
 
 
 def test_candidate_space_cpu_default_is_xla_only():

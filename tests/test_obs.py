@@ -258,3 +258,42 @@ def test_noop_mode_byte_identical_and_timings_pin():
     assert "engine.sweep" in names          # engine frontier sweeps
     assert "graphstore.chunk" in names      # chunk I/O spans
     assert "train.epoch" in names           # per-epoch training spans
+
+
+# ---------------------------------------------------------------------------
+# jax.profiler session: a requested profile either lands or raises
+# ---------------------------------------------------------------------------
+def test_profiler_session_writes_a_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    with obs.profiler_session(str(tmp_path)):
+        jax.block_until_ready(jnp.arange(8.0) * 2)
+    assert list(tmp_path.rglob("*.xplane.pb"))
+    assert _profiler_failures() == 0
+
+
+def _profiler_failures() -> int:
+    snap = obs.registry().snapshot(kinds=("counter",))
+    return snap.get("jax.profiler.failed", {"value": 0})["value"]
+
+
+@pytest.mark.parametrize("phase", ["start_trace", "stop_trace"])
+def test_profiler_session_failure_raises_and_counts(tmp_path, monkeypatch,
+                                                    phase):
+    import jax
+
+    def broken(*args, **kwargs):
+        raise RuntimeError(f"{phase} refused")
+
+    stops = []
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: stops.append(1))
+    monkeypatch.setattr(jax.profiler, phase, broken)
+    with pytest.raises(RuntimeError, match="refused"):
+        with obs.profiler_session(str(tmp_path)):
+            pass
+    assert _profiler_failures() == 1
+    # no directory: no profiler call at all, so nothing can fail
+    with obs.profiler_session(None):
+        pass
+    assert _profiler_failures() == 1
