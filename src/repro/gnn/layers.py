@@ -52,9 +52,11 @@ def aggregate_mean(h: jnp.ndarray, edge_src: jnp.ndarray,
             return csr_aggregate(h, edge_src, edge_dst, edge_weight,
                                  num_nodes=h.shape[0], inv_scale=inv,
                                  config=cfg)
-    msgs = h[edge_src] * edge_weight[:, None]
-    summed = jax.ops.segment_sum(msgs, edge_dst, num_segments=h.shape[0])
-    return summed / jnp.maximum(in_degree[:, None], 1.0)
+    with jax.named_scope("aggregation"):
+        msgs = h[edge_src] * edge_weight[:, None]
+        summed = jax.ops.segment_sum(msgs, edge_dst,
+                                     num_segments=h.shape[0])
+        return summed / jnp.maximum(in_degree[:, None], 1.0)
 
 
 def gcn_layer(params: Dict[str, jnp.ndarray], h: jnp.ndarray,

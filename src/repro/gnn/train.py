@@ -58,6 +58,79 @@ def _finish_epoch_span(sp, loss) -> None:
 
 
 # ---------------------------------------------------------------------------
+# The training entry's phases, shared by the three modes, each under its
+# own span inside ``train.call`` (DESIGN.md §16): with a profiler running
+# the spans land on its clock, beside the device ops they wait for or feed.
+# ---------------------------------------------------------------------------
+def _gather_and_upload(ds: NodeDataset, batch: PartitionBatch,
+                       cfg: GNNConfig, seed: int):
+    """The host gather, then the tensors, parameters and optimizer state
+    on the device: ``(pt, key, params, opt, tensors)``."""
+    with obs.span("train.gather"):
+        pt = gather_partition_tensors(ds, batch)
+    with obs.span("train.upload"):
+        key = jax.random.PRNGKey(seed)
+        params = init_partition_models(key, cfg, ds.num_classes, batch.k)
+        opt = jax.vmap(adamw_init)(params)  # per-partition state (step: [k])
+        tensors = {n: jnp.asarray(v) for n, v in _tensors_dict(pt).items()}
+    return pt, key, params, opt, tensors
+
+
+def _build(fn, *args):
+    """Ahead-of-time build of the jitted ``fn`` for ``args``: tracing and
+    lowering under ``train.lower``, then compiling (or loading from the
+    persistent compile cache) under ``train.compile``. The executable
+    lives for one call of the entry."""
+    with obs.span("train.lower"):
+        lowered = fn.lower(*args)
+    with obs.span("train.compile"):
+        return lowered.compile()
+
+
+def _epoch_keys(key, e: int, k: int):
+    """Epoch ``e``'s dropout keys, one per partition: every mode's
+    schedule."""
+    return jax.random.split(jax.random.fold_in(key, e), k)
+
+
+def _run_epochs(epochs: int, key, k: int, mode: str,
+                run_epoch: Callable[[int, Any], Any],
+                kind_of: Optional[Callable[[int], str]] = None) -> None:
+    """Dispatch ``run_epoch(e, keys) -> loss`` for each epoch under a
+    ``train.epoch`` span (a profiler step). Only with span collection on
+    does the span wait for the step and record its loss."""
+    for e in range(epochs):
+        attrs = {"kind": kind_of(e)} if kind_of else {}
+        with obs.step_span("train.epoch", e, epoch=e, mode=mode,
+                           **attrs) as sp:
+            loss = run_epoch(e, _epoch_keys(key, e, k))
+            if obs.enabled():
+                _finish_epoch_span(sp, loss)
+
+
+def _embed_and_pool(params, integrate: str, embed, tensors, k: int,
+                    pt: "PartitionTensors", n: int, embed_dim: int
+                    ) -> Tuple[PyTree, np.ndarray]:
+    """The embedding pass (``embed(params, tensors)``, built at its first
+    call), the fetch of the ``[k, N_pad, E]`` table and its pooling into
+    the global ``[n, E]`` table."""
+    exe = None
+
+    def emb_fn(p):
+        nonlocal exe
+        if exe is None:
+            exe = _build(embed, p, tensors)
+        return exe(p, tensors)
+
+    with obs.span("train.embed"):
+        params, emb = apply_integration(params, integrate, emb_fn, k)
+    with obs.span("train.fetch"):
+        emb = np.asarray(emb)
+    with obs.span("train.pool"):
+        return params, pool_embeddings(emb, pt, n, embed_dim)
+
+
+# ---------------------------------------------------------------------------
 # Per-partition tensors (host-side assembly)
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
@@ -187,48 +260,34 @@ def train_local(ds: NodeDataset, batch: PartitionBatch, cfg: GNNConfig,
     (pinned in tests/test_graphstore.py). Requires an unsharded run
     (``mesh is None``) and no ``hlo_out``."""
     if sequential and mesh is None and hlo_out is None:
-        return _train_local_sequential(ds, batch, cfg, epochs=epochs, lr=lr,
-                                       seed=seed, integrate=integrate)
-    pt = gather_partition_tensors(ds, batch)
+        with obs.span("train.call", mode="local_sequential", k=batch.k,
+                      epochs=epochs):
+            return _train_local_sequential(ds, batch, cfg, epochs=epochs,
+                                           lr=lr, seed=seed,
+                                           integrate=integrate)
     k = batch.k
-    num_out = ds.num_classes
-    key = jax.random.PRNGKey(seed)
-    params = init_partition_models(key, cfg, num_out, k)
-    opt = jax.vmap(adamw_init)(params)   # per-partition opt state (step: [k])
-    tensors = {n: jnp.asarray(v) for n, v in _tensors_dict(pt).items()}
-
-    step = make_local_train_step(cfg, ds.multilabel, lr)
-    if mesh is not None:
-        shard = NamedSharding(mesh, P("data"))
-        step = jax.jit(step, in_shardings=(shard, shard, shard, shard),
-                       out_shardings=(shard, shard, shard))
-    else:
-        step = jax.jit(step)
-
-    if hlo_out is not None:
-        # AOT-compile once and reuse the executable for stepping — the AOT
-        # path does not populate the jit cache, so calling `step` afterwards
-        # would compile a second time.
-        keys0 = jax.random.split(jax.random.fold_in(key, 0), k)
-        compiled = step.lower(params, opt, tensors, keys0).compile()
-        hlo_out["hlo"] = compiled.as_text()
-        step = compiled
-
-    epochs_ctr = obs.counter("train.epochs")
-    traced = obs.enabled()
-    for e in range(epochs):
-        keys = jax.random.split(jax.random.fold_in(key, e), k)
-        if traced:
-            with obs.span("train.epoch", epoch=e, mode="local") as sp:
-                params, opt, loss = step(params, opt, tensors, keys)
-                _finish_epoch_span(sp, loss)
+    with obs.span("train.call", mode="local", k=k, epochs=epochs):
+        pt, key, params, opt, tensors = _gather_and_upload(ds, batch, cfg,
+                                                           seed)
+        step = make_local_train_step(cfg, ds.multilabel, lr)
+        if mesh is not None:
+            shard = NamedSharding(mesh, P("data"))
+            step = jax.jit(step, in_shardings=(shard, shard, shard, shard),
+                           out_shardings=(shard, shard, shard))
         else:
+            step = jax.jit(step)
+        step = _build(step, params, opt, tensors, _epoch_keys(key, 0, k))
+        if hlo_out is not None:
+            hlo_out["hlo"] = step.as_text()
+
+        def run_epoch(e, keys):
+            nonlocal params, opt
             params, opt, loss = step(params, opt, tensors, keys)
-        epochs_ctr.inc()
-    params, emb = apply_integration(
-        params, integrate, lambda p: compute_embeddings(p, cfg, tensors), k)
-    return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
-                                   cfg.embed_dim)
+            return loss
+
+        _run_epochs(epochs, key, k, "local", run_epoch)
+        return _embed_and_pool(params, integrate, _local_embed(cfg),
+                               tensors, k, pt, ds.graph.n, cfg.embed_dim)
 
 
 def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
@@ -243,17 +302,16 @@ def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
     one partition's tensors are resident on device at a time; the jitted
     single-partition step compiles once (padding makes every partition the
     same shape)."""
-    pt = gather_partition_tensors(ds, batch)
+    with obs.span("train.gather"):
+        pt = gather_partition_tensors(ds, batch)
     k = batch.k
     np_tensors = _tensors_dict(pt)
     key = jax.random.PRNGKey(seed)
     params = init_partition_models(key, cfg, ds.num_classes, k)
     # per-epoch key schedule, identical to the vmapped path's
-    ep_keys = [jax.random.split(jax.random.fold_in(key, e), k)
-               for e in range(epochs)]
+    ep_keys = [_epoch_keys(key, e, k) for e in range(epochs)]
     step1 = jax.jit(make_local_train_step(cfg, ds.multilabel, lr,
                                           per_partition=True))
-    epochs_ctr = obs.counter("train.epochs")
     traced = obs.enabled()
     trained: List[PyTree] = []
     for p in range(k):
@@ -266,7 +324,6 @@ def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
             for e in range(epochs):
                 params_p, opt_p, loss = step1(params_p, opt_p, t_p,
                                               ep_keys[e][p])
-                epochs_ctr.inc()
             if traced and loss is not None:
                 _finish_epoch_span(psp, loss)
         trained.append(jax.tree.map(np.asarray, params_p))
@@ -285,15 +342,19 @@ def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
         return jnp.asarray(np.stack(out))
 
     params, emb = apply_integration(params, integrate, emb_fn, k)
-    return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
-                                   cfg.embed_dim)
+    with obs.span("train.pool"):
+        return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
+                                       cfg.embed_dim)
+
+
+def _local_embed(cfg: GNNConfig):
+    """The local embedding pass, jitted: ``(params, tensors) -> [k, N_pad,
+    E]``."""
+    return jax.jit(jax.vmap(lambda p, t: _forward_one(p, cfg, t)[0]))
 
 
 def compute_embeddings(params, cfg: GNNConfig, tensors) -> jnp.ndarray:
-    def one(p, t):
-        emb, _ = _forward_one(p, cfg, t)
-        return emb
-    return jax.jit(jax.vmap(one))(params, tensors)
+    return _local_embed(cfg)(params, tensors)
 
 
 def apply_integration(params, integrate: Optional[str],
@@ -370,6 +431,7 @@ def make_halo_forward(cfg: GNNConfig, halo: HaloExchangeSpec,
     send_rows = jnp.asarray(halo.send_rows)   # [k, k, H]
     recv_rows = jnp.asarray(halo.recv_rows)   # [k, k, H]
 
+    @jax.named_scope("halo_exchange")
     def refresh(h: jnp.ndarray, my_idx: jnp.ndarray) -> jnp.ndarray:
         # Build what I send to every peer: rows of my h.  [k, H, F]
         mine_send = send_rows[my_idx]                       # [k, H]
@@ -498,46 +560,39 @@ def train_sync(ds: NodeDataset, batch: PartitionBatch,
             f"sync training needs one partition per device: mesh data axis "
             f"is {data_size} but k={k}. On CPU, relaunch with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={k}.")
-    pt = gather_partition_tensors(ds, batch)
-    key = jax.random.PRNGKey(seed)
-    params = init_partition_models(key, cfg, ds.num_classes, k)
-    opt = jax.vmap(adamw_init)(params)
-    tensors = {n: jnp.asarray(v) for n, v in _tensors_dict(pt).items()}
+    with obs.span("train.call", mode="sync", k=k, epochs=epochs):
+        pt, key, params, opt, tensors = _gather_and_upload(ds, batch, cfg,
+                                                           seed)
+        step = _build(make_sync_train_step(cfg, halo, ds.multilabel, mesh,
+                                           lr),
+                      params, opt, tensors, _epoch_keys(key, 0, k))
+        if hlo_out is not None:
+            hlo_out["hlo"] = step.as_text()
 
-    step = make_sync_train_step(cfg, halo, ds.multilabel, mesh, lr)
-    if hlo_out is not None:
-        keys0 = jax.random.split(jax.random.fold_in(key, 0), k)
-        compiled = step.lower(params, opt, tensors, keys0).compile()
-        hlo_out["hlo"] = compiled.as_text()
-        step = compiled
-    epochs_ctr = obs.counter("train.epochs")
-    traced = obs.enabled()
-    for e in range(epochs):
-        keys = jax.random.split(jax.random.fold_in(key, e), k)
-        if traced:
-            with obs.span("train.epoch", epoch=e, mode="sync") as sp:
-                params, opt, loss = step(params, opt, tensors, keys)
-                _finish_epoch_span(sp, loss)
-        else:
+        def run_epoch(e, keys):
+            nonlocal params, opt
             params, opt, loss = step(params, opt, tensors, keys)
-        epochs_ctr.inc()
+            return loss
 
-    forward = make_sync_forward(cfg, halo)
+        _run_epochs(epochs, key, k, "sync", run_epoch)
+        forward = make_sync_forward(cfg, halo)
+        embed = _halo_embed(lambda p, t, i: forward(p, t, i)[0], mesh)
+        return _embed_and_pool(params, integrate, embed, tensors, k, pt,
+                               ds.graph.n, cfg.embed_dim)
 
+
+def _halo_embed(embed_one: Callable, mesh: Mesh):
+    """A halo mode's embedding pass, jitted: ``(params, tensors) -> [k,
+    N_pad, E]``, one partition per ``data`` device, each embedded by
+    ``embed_one(params, t, my_idx)``."""
     def eval_one(p, t):
         p1 = jax.tree.map(lambda x: x[0], p)
         t1 = jax.tree.map(lambda x: x[0], t)
-        emb, _ = forward(p1, t1, jax.lax.axis_index("data"))
-        return emb[None]
+        return embed_one(p1, t1, jax.lax.axis_index("data"))[None]
 
     pspec = P("data")
-    emb_fn = jax.jit(jax.shard_map(eval_one, mesh=mesh,
-                                   in_specs=(pspec, pspec), out_specs=pspec,
-                                   check_vma=False))
-    params, emb = apply_integration(
-        params, integrate, lambda p: emb_fn(p, tensors), k)
-    return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
-                                   cfg.embed_dim)
+    return jax.jit(jax.shard_map(eval_one, mesh=mesh, in_specs=(pspec, pspec),
+                                 out_specs=pspec, check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -665,58 +720,42 @@ def train_stale(ds: NodeDataset, batch: PartitionBatch,
             f"stale training needs one partition per device: mesh data axis "
             f"is {data_size} but k={k}. On CPU, relaunch with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={k}.")
-    pt = gather_partition_tensors(ds, batch)
-    key = jax.random.PRNGKey(seed)
-    params = init_partition_models(key, cfg, ds.num_classes, k)
-    opt = jax.vmap(adamw_init)(params)
-    tensors = {n: jnp.asarray(v) for n, v in _tensors_dict(pt).items()}
-
     schedule = set(stale_exchange_epochs(epochs, sync_period))
-    n_exchange = len(schedule)
-    has_stale_epochs = epochs > n_exchange
-    steps = make_stale_train_steps(cfg, halo, ds.multilabel, mesh, lr)
-    step_ex, step_st, step_fz = (steps["exchange"], steps["stale"],
-                                 steps["frozen"])
-
-    if hlo_out is not None:
-        keys0 = jax.random.split(jax.random.fold_in(key, 0), k)
-        if n_exchange:
-            compiled_ex = step_ex.lower(params, opt, tensors,
-                                        keys0).compile()
-            hlo_out["hlo"] = compiled_ex.as_text()
-            step_ex = compiled_ex
-            if has_stale_epochs:
+    with obs.span("train.call", mode="stale", k=k, epochs=epochs):
+        pt, key, params, opt, tensors = _gather_and_upload(ds, batch, cfg,
+                                                           seed)
+        steps = make_stale_train_steps(cfg, halo, ds.multilabel, mesh, lr)
+        keys0 = _epoch_keys(key, 0, k)
+        if schedule:
+            step_ex = _build(steps["exchange"], params, opt, tensors, keys0)
+            programs = {"hlo": step_ex}
+            if epochs > len(schedule):
                 caches0 = tuple(
-                    jnp.zeros((k,) + s, jnp.float32)
+                    jax.ShapeDtypeStruct((k,) + s, jnp.float32)
                     for s in _stale_cache_shapes(cfg, batch.n_pad))
-                compiled_st = step_st.lower(params, opt, tensors, keys0,
-                                            caches0).compile()
-                hlo_out["hlo_stale"] = compiled_st.as_text()
-                step_st = compiled_st
+                step_st = _build(steps["stale"], params, opt, tensors, keys0,
+                                 caches0)
+                programs["hlo_stale"] = step_st
         else:
-            compiled_fz = step_fz.lower(params, opt, tensors,
-                                        keys0).compile()
             # period=∞ never moves a byte: the frozen step is both the
             # "whole training" program and the between-exchange program
-            hlo_out["hlo"] = compiled_fz.as_text()
-            hlo_out["hlo_stale"] = compiled_fz.as_text()
-            step_fz = compiled_fz
+            step_fz = _build(steps["frozen"], params, opt, tensors, keys0)
+            programs = {"hlo": step_fz, "hlo_stale": step_fz}
+        if hlo_out is not None:
+            hlo_out.update({n: c.as_text() for n, c in programs.items()})
 
-    epochs_ctr = obs.counter("train.epochs")
-    exchanges_ctr = obs.counter("train.stale_exchanges")
-    traced = obs.enabled()
-    caches = None
-    for e in range(epochs):
-        keys = jax.random.split(jax.random.fold_in(key, e), k)
-        kind = ("exchange" if e in schedule
-                else "frozen" if caches is None else "stale")
+        def kind_of(e):
+            return ("exchange" if e in schedule
+                    else "stale" if schedule else "frozen")
 
-        def run_epoch():
+        caches = None
+
+        def run_epoch(e, keys):
             nonlocal params, opt, caches
+            kind = kind_of(e)
             if kind == "exchange":
                 params, opt, loss, caches = step_ex(params, opt, tensors,
                                                     keys)
-                exchanges_ctr.inc()
             elif kind == "frozen":
                 params, opt, loss = step_fz(params, opt, tensors, keys)
             else:
@@ -724,35 +763,17 @@ def train_stale(ds: NodeDataset, batch: PartitionBatch,
                                             caches)
             return loss
 
-        if traced:
-            with obs.span("train.epoch", epoch=e, mode="stale",
-                          kind=kind) as sp:
-                _finish_epoch_span(sp, run_epoch())
-        else:
-            run_epoch()
-        epochs_ctr.inc()
+        _run_epochs(epochs, key, k, "stale", run_epoch, kind_of)
 
-    # Embedding pass mirrors training: a live refresh when the run ever
-    # exchanged (sync limit stays exact), the plain local forward otherwise
-    # (local limit stays exact).
-    forward = make_halo_forward(cfg, halo)
-    eval_mode = "exchange" if n_exchange else "frozen"
-
-    def eval_one(p, t):
-        p1 = jax.tree.map(lambda x: x[0], p)
-        t1 = jax.tree.map(lambda x: x[0], t)
-        emb, _, _ = forward(p1, t1, jax.lax.axis_index("data"),
-                            refresh_mode=eval_mode)
-        return emb[None]
-
-    pspec = P("data")
-    emb_fn = jax.jit(jax.shard_map(eval_one, mesh=mesh,
-                                   in_specs=(pspec, pspec), out_specs=pspec,
-                                   check_vma=False))
-    params, emb = apply_integration(
-        params, integrate, lambda p: emb_fn(p, tensors), k)
-    return params, pool_embeddings(np.asarray(emb), pt, ds.graph.n,
-                                   cfg.embed_dim)
+        # Embedding pass mirrors training: a live refresh when the run ever
+        # exchanged (sync limit stays exact), the plain local forward
+        # otherwise (local limit stays exact).
+        forward = make_halo_forward(cfg, halo)
+        eval_mode = "exchange" if schedule else "frozen"
+        embed = _halo_embed(
+            lambda p, t, i: forward(p, t, i, refresh_mode=eval_mode)[0], mesh)
+        return _embed_and_pool(params, integrate, embed, tensors, k, pt,
+                               ds.graph.n, cfg.embed_dim)
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,12 @@ Differentiation (DESIGN.md §11): ``csr_aggregate_pallas`` carries a
 * ``inv_scale`` (the fused degree normalization) and the arc lists are
   graph *structure*, not trainable data: their cotangents are defined as
   zero (``float0`` for the int arrays).
+
+Names on the device (DESIGN.md §16): the kernels are ``csr_aggregate``
+(forward and transposed aggregation) and ``edge_dot``; each runs with its
+XLA row gathers under ``jax.named_scope("aggregation")``, the scope the jnp
+path's gather and segment-sum share, so that a profile finds every
+path's aggregation ops by it.
 """
 from __future__ import annotations
 
@@ -237,28 +243,31 @@ def _aggregate(h, edge_src, edge_dst, edge_weight, inv_scale, *,
     ft_sz = min(ft_sz, f)
     granule = eb * stream
     grid = (n // nt, f // ft_sz, e // granule)
-    lo, hi = edge_block_ranges(edge_dst, eb)
-    rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
-    out = pl.pallas_call(
-        functools.partial(_agg_kernel, edge_block=eb, stream=stream),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
-            *edge_row_specs(granule),                 # dst, w
-            pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
-            pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
-        ],
-        out_specs=pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
-        out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
-        interpret=interpret,
-    )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
-      inv_scale.reshape(n, 1), rows)
+    with jax.named_scope("aggregation"):
+        lo, hi = edge_block_ranges(edge_dst, eb)
+        rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
+        out = pl.pallas_call(
+            functools.partial(_agg_kernel, edge_block=eb, stream=stream),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
+                pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
+                *edge_row_specs(granule),                 # dst, w
+                pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
+                pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
+            ],
+            out_specs=pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
+            out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
+            interpret=interpret,
+            name="csr_aggregate",
+        )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
+          inv_scale.reshape(n, 1), rows)
     return out.astype(h.dtype)
 
 
 def _edge_dot(a, b, *, interpret: bool, config: KernelConfig) -> jnp.ndarray:
-    """Per-edge row dot <a[e, :], b[e, :]> -> [E], f32, feature-tiled."""
+    """Per-edge row dot <a[e, :], b[e, :]> -> [E], f32, feature-tiled.
+    Callers gather the rows and put both under the aggregation scope."""
     e, f = a.shape
     eb, ft_sz = config.edge_block, min(config.feat_tile, f)
     grid = (e // eb, f // ft_sz)
@@ -272,6 +281,7 @@ def _edge_dot(a, b, *, interpret: bool, config: KernelConfig) -> jnp.ndarray:
         out_specs=pl.BlockSpec((1, eb), lambda i, ft: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, e), jnp.float32),
         interpret=interpret,
+        name="edge_dot",
     )(a, b)
     return out.reshape(e)
 
@@ -305,9 +315,10 @@ def _aggregate_diff_bwd(interpret, config, res, g):
                     ones, interpret=interpret, config=config).astype(h.dtype)
     # w-cotangent: per-edge row dot of h[src] with the scaled cotangent rows.
     g_scaled = g32 * inv.astype(jnp.float32)[:, None]
-    dw = _edge_dot(jnp.take(h.astype(jnp.float32), src, axis=0),
-                   jnp.take(g_scaled, dst, axis=0),
-                   interpret=interpret, config=config).astype(w.dtype)
+    with jax.named_scope("aggregation"):
+        dw = _edge_dot(jnp.take(h.astype(jnp.float32), src, axis=0),
+                       jnp.take(g_scaled, dst, axis=0),
+                       interpret=interpret, config=config).astype(w.dtype)
     zero_int = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     # inv_scale is graph structure (degree normalization): zero by design.
     return (dh, zero_int(src), zero_int(dst), dw, jnp.zeros_like(inv),

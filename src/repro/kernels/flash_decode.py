@@ -100,5 +100,6 @@ def flash_decode_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((g, LANES), jnp.float32),
         ],
         interpret=interpret_mode(),
+        name="flash_decode",
     )(length, qg, kt, vt)
     return out.reshape(hq, d).astype(q.dtype)

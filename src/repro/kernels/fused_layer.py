@@ -66,10 +66,11 @@ def fused_gcn_reference(h, edge_src, edge_dst, edge_weight, inv_scale,
                         w, b, activate: bool = True) -> jnp.ndarray:
     """jnp composition of the fused layer: oracle + the "xla" strategy."""
     n = h.shape[0]
-    msgs = (jnp.take(h, edge_src, axis=0).astype(jnp.float32)
-            * edge_weight.astype(jnp.float32)[:, None])
-    agg = jax.ops.segment_sum(msgs, edge_dst, num_segments=n)
-    agg = agg * inv_scale.astype(jnp.float32)[:, None]
+    with jax.named_scope("aggregation"):
+        msgs = (jnp.take(h, edge_src, axis=0).astype(jnp.float32)
+                * edge_weight.astype(jnp.float32)[:, None])
+        agg = jax.ops.segment_sum(msgs, edge_dst, num_segments=n)
+        agg = agg * inv_scale.astype(jnp.float32)[:, None]
     z = agg @ w.astype(jnp.float32) + b.astype(jnp.float32)[None, :]
     # jax.nn.relu, NOT jnp.maximum: their values agree but their gradients
     # at z == 0 differ (relu' = 0 vs maximum's 0.5 tie split) — and z == 0
@@ -130,33 +131,35 @@ def _fused_forward(h, edge_src, edge_dst, edge_weight, inv_scale, wmat, b,
     ft_sz = min(config.feat_tile, f)
     granule = eb * stream
     grid = (n // nt, f // ft_sz, e // granule)
-    lo, hi = edge_block_ranges(edge_dst, eb)
-    rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
-    agg, out = pl.pallas_call(
-        functools.partial(_fused_kernel, edge_block=eb, stream=stream,
-                          activate=activate),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
-            pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
-            *edge_row_specs(granule),                 # dst, w
-            pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
-            pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
-            pl.BlockSpec((ft_sz, fo), lambda i, ft, sb: (ft, 0)),
-            pl.BlockSpec((1, fo), lambda i, ft, sb: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
-            pl.BlockSpec((nt, fo), lambda i, ft, sb: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, f), jnp.float32),
-            jax.ShapeDtypeStruct((n, fo), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((nt, fo), jnp.float32)],
-        interpret=interpret,
-    )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
-      inv_scale.reshape(n, 1), rows, wmat, b.reshape(1, fo))
+    with jax.named_scope("aggregation"):
+        lo, hi = edge_block_ranges(edge_dst, eb)
+        rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
+        agg, out = pl.pallas_call(
+            functools.partial(_fused_kernel, edge_block=eb, stream=stream,
+                              activate=activate),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
+                pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
+                *edge_row_specs(granule),                 # dst, w
+                pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
+                pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
+                pl.BlockSpec((ft_sz, fo), lambda i, ft, sb: (ft, 0)),
+                pl.BlockSpec((1, fo), lambda i, ft, sb: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
+                pl.BlockSpec((nt, fo), lambda i, ft, sb: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, f), jnp.float32),
+                jax.ShapeDtypeStruct((n, fo), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((nt, fo), jnp.float32)],
+            interpret=interpret,
+            name="gcn_fused_layer",
+        )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
+          inv_scale.reshape(n, 1), rows, wmat, b.reshape(1, fo))
     return out, agg
 
 
@@ -197,9 +200,10 @@ def _fused_diff_bwd(interpret, activate, config, res, g):
     dh = _aggregate(da, jnp.take(dst, perm), jnp.take(src, perm), rev_w,
                     ones, interpret=interpret, config=config).astype(h.dtype)
     da_scaled = da * inv.astype(jnp.float32)[:, None]
-    dw = _edge_dot(jnp.take(h.astype(jnp.float32), src, axis=0),
-                   jnp.take(da_scaled, dst, axis=0),
-                   interpret=interpret, config=config).astype(w.dtype)
+    with jax.named_scope("aggregation"):
+        dw = _edge_dot(jnp.take(h.astype(jnp.float32), src, axis=0),
+                       jnp.take(da_scaled, dst, axis=0),
+                       interpret=interpret, config=config).astype(w.dtype)
     zero_int = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     return (dh, zero_int(src), zero_int(dst), dw, jnp.zeros_like(inv),
             dwmat.astype(wmat.dtype), db, zero_int(perm))

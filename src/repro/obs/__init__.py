@@ -26,6 +26,16 @@ cache/compile books) and snapshots stay deterministic across processes.
 ``obs.enable()`` turns span collection on; ``obs.export_trace(path)``
 writes Chrome trace-event JSON (open in Perfetto / ``chrome://tracing``);
 ``python -m repro.obs summarize out.json`` aggregates it per span name.
+
+**The profiler sink.** While a JAX profiler session runs
+(``jax.profiler.start_trace``), every span is also written to the
+profiler's trace as a ``TraceMe`` of the same name carrying the span's
+scalar attributes, whether or not ``obs.enable()`` was called, so the
+spans sit on the same clock as the device's ops. :func:`step_span` writes
+its span as a profiler step (what ``jax.profiler.StepTraceAnnotation``
+writes). The sink never syncs the device, and jaxlib's profiler is
+imported at the first span, not here: this package stays importable
+without JAX.
 """
 from __future__ import annotations
 
@@ -36,8 +46,8 @@ from .sampler import jax_profiler_session, peak_rss_bytes, sample_memory
 from .trace import Span, Tracer
 
 __all__ = [
-    "SCHEMA_VERSION", "enabled", "enable", "disable", "span", "counter",
-    "gauge", "histogram", "registry", "tracer", "export_trace",
+    "SCHEMA_VERSION", "enabled", "enable", "disable", "span", "step_span",
+    "counter", "gauge", "histogram", "registry", "tracer", "export_trace",
     "trace_document", "sample_memory_now", "profiler_session", "reset",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "Tracer",
     "peak_rss_bytes",
@@ -70,6 +80,53 @@ class _NoopSpan:
 
 _NOOP_SPAN = _NoopSpan()
 
+# jaxlib's TraceMe, looked up by the first span (see _profiling)
+_TraceMe: Any = None
+
+
+def _profiling() -> bool:
+    """Whether a JAX profiler session is running. The first call looks up
+    jaxlib's ``TraceMe`` and rebinds this name to its ``is_enabled`` (or to
+    a constant False where jaxlib is absent), so later spans pay one C
+    call."""
+    global _TraceMe, _profiling
+    try:
+        from jaxlib._profiler import TraceMe
+    except ImportError:
+        _profiling = lambda: False  # noqa: E731
+    else:
+        _TraceMe = TraceMe
+        _profiling = TraceMe.is_enabled
+    return _profiling()
+
+
+class _ProfiledSpan:
+    """A span written to the profiler's trace, and to the tracer when span
+    collection is on; ``with`` yields the tracer's span (or the no-op)."""
+
+    __slots__ = ("_me", "_inner")
+
+    def __init__(self, me, inner):
+        self._me = me
+        self._inner = inner
+
+    def __enter__(self):
+        self._me.__enter__()
+        return self._inner.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        try:
+            return self._inner.__exit__(exc_type, exc, tb)
+        finally:
+            self._me.__exit__(exc_type, exc, tb)
+
+
+def _profiled(name: str, attrs: Dict[str, Any], **marks: Any):
+    scalars = {k: v for k, v in attrs.items()
+               if isinstance(v, (bool, int, float, str))}
+    inner = _tracer.span(name, **attrs) if _enabled else _NOOP_SPAN
+    return _ProfiledSpan(_TraceMe(name, **scalars, **marks), inner)
+
 
 def enabled() -> bool:
     """Whether span collection is on (metrics are always on)."""
@@ -87,7 +144,20 @@ def disable() -> None:
 
 
 def span(name: str, **attrs: Any):
-    """Open a nested span; no-op (shared singleton) when disabled."""
+    """Open a nested span; no-op (shared singleton) when disabled and no
+    profiler runs."""
+    if _profiling():
+        return _profiled(name, attrs)
+    if not _enabled:
+        return _NOOP_SPAN
+    return _tracer.span(name, **attrs)
+
+
+def step_span(name: str, step_num: int, **attrs: Any):
+    """:func:`span` that the profiler records as step ``step_num`` (as
+    ``jax.profiler.StepTraceAnnotation`` does)."""
+    if _profiling():
+        return _profiled(name, attrs, _r=1, step_num=step_num)
     if not _enabled:
         return _NOOP_SPAN
     return _tracer.span(name, **attrs)

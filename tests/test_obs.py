@@ -3,7 +3,8 @@
 Covers the contracts the rest of the stack leans on: span
 nesting/exception-safety, trace JSON schema validity, byte-identical
 pipeline results in no-op mode, deterministic counter snapshots across
-processes, and the ``PipelineReport.timings``-is-a-view-over-spans pin.
+processes, the ``PipelineReport.timings``-is-a-view-over-spans pin, and
+the profiler sink (the training entry's spans in a profiler trace).
 """
 from __future__ import annotations
 
@@ -297,3 +298,112 @@ def test_profiler_session_failure_raises_and_counts(tmp_path, monkeypatch,
     with obs.profiler_session(None):
         pass
     assert _profiler_failures() == 1
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: spans on the profiler's clock, no sync, no change to
+# the Chrome export
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def tiny_job():
+    from repro.core import (build_partition_batch, leiden_fusion,
+                            make_arxiv_like)
+    from repro.gnn import GNNConfig
+    ds = make_arxiv_like(n=200, feature_dim=8, num_classes=4, seed=3)
+    batch = build_partition_batch(ds.graph, leiden_fusion(ds.graph, 2),
+                                  scheme="repli")
+    cfg = GNNConfig(kind="gcn", feature_dim=8, hidden_dim=8, embed_dim=8,
+                    num_layers=2, dropout=0.5)
+    return ds, batch, cfg
+
+
+def _bench_trace():
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import trace
+    return trace
+
+
+def test_profiler_trace_holds_the_training_entrys_spans(tmp_path, tiny_job):
+    import jax
+    from repro.gnn import train_local
+    trace = _bench_trace()
+    epochs = 3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with jax.profiler.TraceAnnotation("bench.call"):
+                train_local(*tiny_job, epochs=epochs)
+    finally:
+        jax.profiler.stop_trace()
+    assert obs.tracer().event_count() == 0      # obs itself stayed off
+    tr = trace.load(trace.find_xplane(str(tmp_path)),
+                    device_plane="^/host:CPU$", op_line="^tf_XLA",
+                    op_stat="hlo_op")
+    (_, c0, c1), = [s for s in tr.spans if s[0] == "bench.call"]
+    train = [h for h in tr.host if h[0].startswith("train.")]
+    (_, t0, t1), = [h for h in train if h[0] == "train.call"]
+    assert c0 <= t0 < t1 <= c1
+    # the direct children of train.call, in order
+    children = [h for h in train if h[0] != "train.call"
+                and not any(o is not h and o[0] != "train.call"
+                            and o[1] <= h[1] and h[2] <= o[2]
+                            for o in train)]
+    assert all(t0 <= s and e <= t1 for _, s, e in children)
+    assert [n for n, _, _ in children] == (
+        ["train.gather", "train.upload", "train.lower", "train.compile"]
+        + ["train.epoch"] * epochs
+        + ["train.embed", "train.fetch", "train.pool"])
+    # the embedding pass is built inside train.embed
+    (_, e0, e1), = [h for h in children if h[0] == "train.embed"]
+    assert [n for n, s, e in train if e0 < s and e <= e1] == \
+        ["train.lower", "train.compile"]
+
+
+def test_span_is_the_noop_singleton_again_after_a_profile(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        live = obs.span("a.b", x=1)
+        with obs.step_span("a.step", 0, x=1) as sp:
+            sp.set(loss=1.0)                     # dropped: obs is off
+    finally:
+        jax.profiler.stop_trace()
+    assert live is not obs.span("a.b", x=1)
+    assert obs.span("a.b", x=1) is obs.span("c.d") is \
+        obs.step_span("a.step", 3)
+    assert obs.tracer().event_count() == 0
+
+
+def _epochs_and_export(tiny_job, profile_dir=None):
+    import jax
+    from repro.gnn import train_local
+    obs.reset()
+    obs.enable()
+    if profile_dir is not None:
+        jax.profiler.start_trace(profile_dir)
+    try:
+        train_local(*tiny_job, epochs=3)
+    finally:
+        if profile_dir is not None:
+            jax.profiler.stop_trace()
+    doc = obs.trace_document()
+    losses = [(s.attrs["epoch"], s.attrs["loss"])
+              for s in obs.tracer().spans() if s.name == "train.epoch"]
+    events = [(e["name"], e["args"]) for e in doc["traceEvents"]
+              if e.get("ph") == "X"]
+    return losses, events, doc
+
+
+def test_enabled_export_and_losses_unchanged_under_the_profiler(tmp_path,
+                                                                tiny_job):
+    plain = _epochs_and_export(tiny_job)
+    profiled = _epochs_and_export(tiny_job, str(tmp_path))
+    assert [e for e, _ in plain[0]] == [0, 1, 2]
+    assert profiled[0] == plain[0]
+    assert profiled[1] == plain[1]
+    assert validate_trace(profiled[2]) == []
+    first = next(args for name, args in plain[1] if name == "train.epoch")
+    assert first["epoch"] == 0 and first["mode"] == "local"
