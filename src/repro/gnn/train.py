@@ -68,12 +68,27 @@ def _gather_and_upload(ds: NodeDataset, batch: PartitionBatch,
     on the device: ``(pt, key, params, opt, tensors)``."""
     with obs.span("train.gather"):
         pt = gather_partition_tensors(ds, batch)
+    if obs.enabled() and cfg.use_kernel:
+        _record_stream_share(pt, cfg)
     with obs.span("train.upload"):
         key = jax.random.PRNGKey(seed)
         params = init_partition_models(key, cfg, ds.num_classes, batch.k)
         opt = jax.vmap(adamw_init)(params)  # per-partition state (step: [k])
         tensors = {n: jnp.asarray(v) for n, v in _tensors_dict(pt).items()}
     return pt, key, params, opt, tensors
+
+
+def _record_stream_share(pt: "PartitionTensors", cfg: GNNConfig) -> None:
+    """Gauge ``kernels.stream_share``: the share of (node tile, edge
+    granule) pairs the Pallas aggregation streams for the forward arc
+    lists, under the kernel config the first layer resolves. Host-side,
+    from the gathered batch; nothing when that config is not Pallas."""
+    from repro.kernels import get_config, streamed_pairs
+    _, n_pad, f = pt.features.shape
+    config = get_config(n_pad, pt.edge_dst.shape[1], f)
+    if config.uses_pallas:
+        streamed, dense = streamed_pairs(pt.edge_dst, n_pad, config)
+        obs.gauge("kernels.stream_share").set(streamed / max(dense, 1))
 
 
 def _build(fn, *args):

@@ -124,10 +124,11 @@ def vmem_bytes(bucket: ShapeBucket, cfg: KernelConfig,
                f_out: Optional[int] = None) -> int:
     """f32 VMEM working set of one fused-layer grid step (DESIGN.md §14).
 
-    Pallas double-buffers every blocked operand: the streamed
-    ``[granule, FT]`` block of source rows (gathered by XLA before the
-    call), the granule's dst and weight rows (``[1, granule]``, padded to 8
-    sublanes), the ``[NT, 1]`` inverse-degree column (padded to 128 lanes),
+    Every streamed or blocked operand has two buffers — the kernel's own
+    two slots for the ``[granule, FT]`` block of source rows (gathered by
+    XLA before the call) and the granule's dst and weight rows
+    (``[1, granule]``, padded to 8 sublanes), Pallas's pipeline for the
+    ``[NT, 1]`` inverse-degree column (padded to 128 lanes),
     the ``[FT, FO]`` weight block and ``[1, FO]`` bias, and the aggregate
     and output tiles. Single copies: the ``[NT, FO]`` dense accumulator and
     the ``[NT, EB]`` one-hot scatter with its iota. No term grows with N."""

@@ -7,11 +7,11 @@ full [N, F] round trip plus a second kernel launch per layer. This kernel keeps
 the aggregate tile in VMEM and runs the dense epilogue on it while it is
 still resident, following the fused-epilogue idiom of
 ``kernels/flash_decode.py`` (accumulator scratch + ``pl.when`` init/finish
-on the streaming grid dimension):
+over the feature-tile grid dimension):
 
-    grid = (node tiles i, feature tiles ft, edge granules sb); sb fastest
-    per (i, ft):   agg[i, ft] = Σ_sb onehot-matmul(edge granule sb)
-    at last sb:    agg[i, ft] *= inv[i]                  # mean epilogue
+    grid = (partitions p, node tiles i, feature tiles ft); ft fastest
+    per (i, ft):   agg[i, ft] = Σ_{g in [g0[i], g1[i]]} onehot-matmul(granule g)
+                   agg[i, ft] *= inv[i]                  # mean epilogue
                    zacc[i]   += agg[i, ft] @ W[ft, :]    # dense, FT-chunked
     at last (ft):  out[i] = relu(zacc[i] + b)            # bias + act
 
@@ -20,9 +20,12 @@ semantics), so the dense transform is accumulated feature-tile by
 feature-tile without the aggregate ever leaving VMEM. The aggregate is
 *also* written out — the backward pass needs it for dW, and XLA
 dead-code-eliminates the store on forward-only calls. The XLA row gather
-before the call, the edge streaming and the degenerate-tile skip are shared
-with :mod:`repro.kernels.csr_aggregate` (same SMEM lo/hi fast path, same
-2-D operand blocks); the bias travels as a ``[1, FO]`` row.
+before the call, the per-tile granule range streamed through two-slot
+VMEM buffers, the degenerate-block skip and the partition axis that
+``vmap`` folds into are shared with :mod:`repro.kernels.csr_aggregate`; a
+tile whose range is empty (no in-arcs) still runs the epilogue, so its
+rows read ``act(b)``. The weight block travels as ``[FT, FO]``, the bias
+as a ``[1, FO]`` row.
 
 Backward: with A the weighted adjacency, ``agg = diag(inv)·A·h``,
 ``z = agg@W + b``, ``out = act(z)``:
@@ -55,9 +58,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .autotune import KernelConfig, interpret_mode
 from .csr_aggregate import (DEFAULT_CONFIG, ShapeContractError, _aggregate,
-                            _edge_dot, _node_tile, accumulate_edge_granule,
-                            check_shape_contract, edge_block_ranges,
-                            edge_row_specs)
+                            _edge_dot, _node_tile, check_shape_contract,
+                            partition_batched, stream_inputs, stream_specs,
+                            stream_tile_granules)
 
 LANES = 128
 
@@ -80,86 +83,80 @@ def fused_gcn_reference(h, edge_src, edge_dst, edge_weight, inv_scale,
     return out.astype(h.dtype)
 
 
-def _fused_kernel(lo_ref, hi_ref, dst_ref, w_ref, inv_ref, rows_ref,
-                  wmat_ref, b_ref, agg_ref, out_ref, zacc_ref, *,
-                  edge_block: int, stream: int, activate: bool):
-    ftid = pl.program_id(1)
-    sb = pl.program_id(2)
-    num_ft = pl.num_programs(1)
-    last_sb = sb == pl.num_programs(2) - 1
-
-    @pl.when(sb == 0)
-    def _init():
-        agg_ref[...] = jnp.zeros_like(agg_ref)
-
-    accumulate_edge_granule(lo_ref, hi_ref, dst_ref, w_ref, rows_ref,
-                            agg_ref, edge_block=edge_block, stream=stream,
-                            granule_idx=sb)
+def _fused_kernel(g0_ref, g1_ref, lo_ref, hi_ref, dst_hbm, w_hbm, rows_hbm,
+                  inv_ref, wmat_ref, b_ref, agg_ref, out_ref, dst_buf, w_buf,
+                  rows_buf, sems, zacc_ref, *, edge_block: int, stream: int,
+                  activate: bool):
+    ftid = pl.program_id(2)
+    agg_ref[...] = jnp.zeros_like(agg_ref)
+    stream_tile_granules(g0_ref, g1_ref, lo_ref, hi_ref, dst_hbm, w_hbm,
+                         rows_hbm, agg_ref, dst_buf, w_buf, rows_buf, sems,
+                         edge_block=edge_block, stream=stream)
 
     # fused epilogue: normalization, then the dense transform on the still-
     # resident aggregate tile (zacc accumulates over feature tiles), then
     # bias + activation once the last feature tile lands.
-    @pl.when(last_sb)
-    def _normalize():
-        agg_ref[...] = agg_ref[...] * inv_ref[...]       # [NT, 1] column
+    agg_ref[...] = agg_ref[...] * inv_ref[...]           # [NT, 1] column
 
-    @pl.when(jnp.logical_and(last_sb, ftid == 0))
+    @pl.when(ftid == 0)
     def _zacc_init():
         zacc_ref[...] = jnp.zeros_like(zacc_ref)
 
-    @pl.when(last_sb)
-    def _dense():
-        zacc_ref[...] += jax.lax.dot(
-            agg_ref[...], wmat_ref[...].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+    zacc_ref[...] += jax.lax.dot(
+        agg_ref[...], wmat_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_and(last_sb, ftid == num_ft - 1))
+    @pl.when(ftid == pl.num_programs(2) - 1)
     def _finish():
         z = zacc_ref[...] + b_ref[...].astype(jnp.float32)   # [1, FO] row
         out_ref[...] = jnp.maximum(z, 0.0) if activate else z
 
 
+def _fused_call(g0, g1, lo, hi, dst, w, rows, inv, wmat, b, *,
+                activate: bool, interpret: bool, config: KernelConfig):
+    """The fused-layer ``pallas_call`` over a leading partition axis:
+    returns ``[agg [P, N, F], out [P, N, FO]]``."""
+    parts, n, _ = inv.shape
+    f, fo = rows.shape[-1], wmat.shape[-1]
+    nt = _node_tile(n, config.node_tile)
+    ft_sz = min(config.feat_tile, f)
+    in_specs, scratch = stream_specs((g0, g1, lo, hi), dst, w, rows, config)
+    return pl.pallas_call(
+        functools.partial(_fused_kernel, edge_block=config.edge_block,
+                          stream=config.stream, activate=activate),
+        grid=(parts, n // nt, f // ft_sz),
+        in_specs=[
+            *in_specs,
+            pl.BlockSpec((None, nt, 1), lambda p, i, ft: (p, i, 0)),
+            pl.BlockSpec((None, ft_sz, fo), lambda p, i, ft: (p, ft, 0)),
+            pl.BlockSpec((None, 1, fo), lambda p, i, ft: (p, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, nt, ft_sz), lambda p, i, ft: (p, i, ft)),
+            pl.BlockSpec((None, nt, fo), lambda p, i, ft: (p, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((parts, n, f), jnp.float32),
+            jax.ShapeDtypeStruct((parts, n, fo), jnp.float32),
+        ],
+        scratch_shapes=[*scratch, pltpu.VMEM((nt, fo), jnp.float32)],
+        interpret=interpret,
+        name="gcn_fused_layer",
+    )(g0, g1, lo, hi, dst, w, rows, inv, wmat, b)
+
+
 def _fused_forward(h, edge_src, edge_dst, edge_weight, inv_scale, wmat, b,
                    *, activate: bool, interpret: bool, config: KernelConfig):
     """Aligned-domain fused layer: returns (out [N, FO], agg [N, F])."""
-    n, f = h.shape
-    e = edge_src.shape[0]
+    n = h.shape[0]
     fo = wmat.shape[1]
-    nt = _node_tile(n, config.node_tile)
-    eb, stream = config.edge_block, config.stream
-    ft_sz = min(config.feat_tile, f)
-    granule = eb * stream
-    grid = (n // nt, f // ft_sz, e // granule)
+    call = partition_batched(functools.partial(
+        _fused_call, activate=activate, interpret=interpret, config=config))
     with jax.named_scope("aggregation"):
-        lo, hi = edge_block_ranges(edge_dst, eb)
-        rows = jnp.take(h, edge_src, axis=0)     # XLA gather: [E, F]
-        agg, out = pl.pallas_call(
-            functools.partial(_fused_kernel, edge_block=eb, stream=stream,
-                              activate=activate),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),    # lo
-                pl.BlockSpec(memory_space=pltpu.SMEM),    # hi
-                *edge_row_specs(granule),                 # dst, w
-                pl.BlockSpec((nt, 1), lambda i, ft, sb: (i, 0)),
-                pl.BlockSpec((granule, ft_sz), lambda i, ft, sb: (sb, ft)),
-                pl.BlockSpec((ft_sz, fo), lambda i, ft, sb: (ft, 0)),
-                pl.BlockSpec((1, fo), lambda i, ft, sb: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((nt, ft_sz), lambda i, ft, sb: (i, ft)),
-                pl.BlockSpec((nt, fo), lambda i, ft, sb: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((n, f), jnp.float32),
-                jax.ShapeDtypeStruct((n, fo), jnp.float32),
-            ],
-            scratch_shapes=[pltpu.VMEM((nt, fo), jnp.float32)],
-            interpret=interpret,
-            name="gcn_fused_layer",
-        )(lo, hi, edge_dst.reshape(1, e), edge_weight.reshape(1, e),
-          inv_scale.reshape(n, 1), rows, wmat, b.reshape(1, fo))
+        agg, out = call(
+            *stream_inputs(h, edge_src, edge_dst, edge_weight, config),
+            inv_scale.reshape(n, 1), wmat, b.reshape(1, fo))
     return out, agg
 
 
@@ -193,12 +190,11 @@ def _fused_diff_bwd(interpret, activate, config, res, g):
     mm = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
     dwmat = mm(agg.T, gz)                                # [F, FO]
     da = mm(gz, wmat.astype(jnp.float32).T)              # [N, F]
-    ones = jnp.ones((h.shape[0],), jnp.float32)
     # dh: transpose aggregation over the reversed src-sorted arc list,
     # normalization folded into the reverse weights (same kernel and cfg).
     rev_w = jnp.take(w.astype(jnp.float32) * jnp.take(inv, dst), perm)
     dh = _aggregate(da, jnp.take(dst, perm), jnp.take(src, perm), rev_w,
-                    ones, interpret=interpret, config=config).astype(h.dtype)
+                    None, interpret=interpret, config=config).astype(h.dtype)
     da_scaled = da * inv.astype(jnp.float32)[:, None]
     with jax.named_scope("aggregation"):
         dw = _edge_dot(jnp.take(h.astype(jnp.float32), src, axis=0),

@@ -10,9 +10,11 @@ jnp segment-sum in :mod:`repro.gnn.layers`, the oracle in
 :mod:`repro.kernels.ref`, and the Pallas kernels): *padding arcs carry
 weight 0 and may point at any in-range row; zero weight is what makes them
 no-ops, not where they park.* By convention :mod:`repro.core.assemble`
-parks its padding arcs at row ``n_pad - 1`` (keeps ``edge_dst`` sorted),
-while the alignment padding added here points at row 0 — both are no-ops on
-both paths, which ``tests/test_kernels.py`` pins.
+parks its padding arcs at row ``n_pad - 1``, and the alignment padding
+added here repeats the list's last destination (its source is row 0):
+both keep a sorted ``edge_dst`` sorted, which is what keeps each node
+tile's granule range tight (:mod:`repro.kernels.csr_aggregate`). Both are
+no-ops on both paths, which ``tests/test_kernels.py`` pins.
 
 **Strategy dispatch** (DESIGN.md §14): the tiling/strategy choice lives in
 a :class:`repro.kernels.autotune.KernelConfig`, resolved per (backend,
@@ -23,6 +25,9 @@ state inside a jit, so a cache update can never serve a stale compile.
 from __future__ import annotations
 
 import functools
+from typing import Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -44,24 +49,63 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int, value=0) -> jnp.ndarray:
     return jnp.pad(x, pads, constant_values=value)
 
 
+def _padded_rows(n: int, config: KernelConfig) -> int:
+    """Rows after the node padding: a multiple of 8, and of the node tile
+    past one tile."""
+    n_pad = -(-n // 8) * 8
+    return -(-n_pad // config.node_tile) * config.node_tile \
+        if n_pad > config.node_tile else n_pad
+
+
 def _pad_graph(h, edge_src, edge_dst, edge_weight, inv_scale,
                config: KernelConfig):
     """Pad (h, arcs, inv) to the config's tile contract. Alignment arcs
-    carry weight 0 and park at row 0 — a no-op per the padding contract."""
+    carry weight 0, start at row 0 and repeat the last destination — a
+    no-op per the padding contract that keeps a sorted list sorted."""
     n = h.shape[0]
-    hp = _pad_to(_pad_to(h, config.feat_tile, 1), 8, 0)
-    if hp.shape[0] > config.node_tile:
-        hp = _pad_to(hp, config.node_tile, 0)
-    n_pad = hp.shape[0]
+    n_pad = _padded_rows(n, config)
+    hp = _pad_to(h, config.feat_tile, 1)
+    hp = jnp.pad(hp, ((0, n_pad - n), (0, 0)))
     granule = config.edge_granule
     es = _pad_to(edge_src, granule, 0)
-    ed = _pad_to(edge_dst, granule, 0)
+    ed = edge_dst
+    if ed.shape[0] % granule:
+        ed = jax.lax.pad(ed, ed[-1], ((0, -ed.shape[0] % granule, 0),))
     ew = _pad_to(edge_weight, granule, 0)
     inv = None
     if inv_scale is not None:
         inv = jnp.pad(inv_scale.astype(jnp.float32), (0, n_pad - n),
                       constant_values=1.0)
     return hp, es, ed, ew, inv, n_pad
+
+
+def streamed_pairs(edge_dst: np.ndarray, n_pad: int,
+                   config: KernelConfig) -> Tuple[int, int]:
+    """``(streamed, dense)``: the (node tile, edge granule) pairs the
+    aggregation kernels stream for the arc list ``edge_dst`` (``[E]``, or
+    ``[k, E]`` summed over partitions) of a graph of ``n_pad`` rows, and
+    the pairs of a grid over every tile and granule — per feature tile,
+    after this module's padding. Host-side numpy, the twin of
+    :func:`repro.kernels.csr_aggregate.tile_granule_ranges`."""
+    dst = np.asarray(edge_dst, np.int64)
+    dst = dst.reshape(-1, dst.shape[-1])
+    rows = _padded_rows(n_pad, config)
+    nt = min(rows, config.node_tile)
+    granule = config.edge_granule
+    extra = -dst.shape[1] % granule
+    if extra:
+        dst = np.concatenate([dst, np.repeat(dst[:, -1:], extra, axis=1)],
+                             axis=1)
+    granules = dst.reshape(len(dst), -1, granule)
+    glo, ghi = granules.min(axis=2), granules.max(axis=2)        # [k, G]
+    starts = np.arange(rows // nt)[:, None] * nt                  # [T, 1]
+    meets = ((ghi[:, None, :] >= starts)
+             & (glo[:, None, :] < starts + nt))                   # [k, T, G]
+    g = np.arange(granules.shape[1])
+    g0 = np.where(meets, g, granules.shape[1]).min(axis=2)
+    g1 = np.where(meets, g, -1).max(axis=2)
+    streamed = int(np.maximum(g1 - g0 + 1, 0).sum())
+    return streamed, int(meets.size)
 
 
 @functools.partial(jax.jit, static_argnames=("num_nodes", "config"))

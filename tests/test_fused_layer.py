@@ -205,6 +205,105 @@ def test_gnn_forward_grads_match_jnp_under_forced_pallas():
 
 
 # ---------------------------------------------------------------------------
+# per-tile granule ranges: each node tile streams only the granules whose
+# dst range meets it, so every arc layout must still land every arc
+# ---------------------------------------------------------------------------
+def _range_config(strategy):
+    """Small tiles: several node tiles and granules at a test's size."""
+    return KernelConfig(strategy=strategy, node_tile=64, edge_block=32,
+                        feat_tile=128, stream=2)
+
+
+def _arc_layout(kind, seed, n=300, f=20, e=700, fo=24):
+    """A graph whose arc list has the layout ``kind``; e is no multiple of
+    the granule, so the wrapper's alignment padding is always there.
+
+    - ``k8_like``: real arcs only into the first third of the rows,
+      sorted, then assemble's weight-0 padding parked at row N-1;
+    - ``empty_tiles``: sorted arcs into rows 0-39 and 200-229 only, so
+      node tiles 1 and 2 have no arcs;
+    - ``unsorted``: arcs in random order, the first and the last granule
+      each meeting every node tile."""
+    rng = np.random.default_rng(seed)
+    w_edge = rng.random(e).astype(np.float32)
+    if kind == "k8_like":
+        dst = np.full(e, n - 1)
+        dst[:e - 90] = np.sort(rng.integers(0, n // 3, e - 90))
+        w_edge[e - 90:] = 0.0
+    elif kind == "empty_tiles":
+        dst = np.sort(np.concatenate([rng.integers(0, 40, e // 2),
+                                      rng.integers(200, 230, e - e // 2)]))
+    else:
+        spread = np.arange(0, n, 64)
+        middle = rng.integers(0, n, e - 2 * spread.size)
+        dst = np.concatenate([spread, middle, spread[::-1]])
+    src = rng.integers(0, n, e)
+    deg = np.bincount(dst, weights=w_edge > 0, minlength=n)
+    params = {"w": jnp.asarray(rng.normal(size=(f, fo)) * 0.3, jnp.float32),
+              "b": jnp.asarray(rng.normal(size=(fo,)) * 0.1, jnp.float32)}
+    arrays = (rng.normal(size=(n, f)), src, dst, w_edge, deg)
+    dtypes = (jnp.float32, jnp.int32, jnp.int32, jnp.float32, jnp.float32)
+    return params, tuple(jnp.asarray(a, d) for a, d in zip(arrays, dtypes))
+
+
+def _layer_loss(use_kernel):
+    from repro.gnn.layers import gcn_layer
+
+    def loss(params, h, src, dst, w_edge, deg):
+        out = gcn_layer(params, h, src, dst, w_edge, deg,
+                        use_kernel=use_kernel)
+        return jnp.sum(out * jnp.cos(out)), out
+    return jax.value_and_grad(loss, argnums=(0, 1, 4), has_aux=True)
+
+
+def _assert_close_tree(got, want, tol):
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=tol, atol=tol), got, want)
+
+
+@pytest.mark.parametrize("kind", ["k8_like", "empty_tiles", "unsorted"])
+@pytest.mark.parametrize("strategy", ["pallas_fused", "pallas"])
+def test_granule_ranges_layout_value_and_grad(kind, strategy):
+    """Value, and the gradient w.r.t. the parameters, h and the edge
+    weights (the transposed pass and the edge dot), of a GCN layer
+    through the kernels match the segment-sum path on each layout."""
+    params, args = _arc_layout(kind, 3)
+    (_, ref), ref_g = _layer_loss(False)(params, *args)
+    with override(_range_config(strategy)):
+        (_, out), g = _layer_loss(True)(params, *args)
+    _assert_close_tree(out, ref, 3e-5)
+    _assert_close_tree(g, ref_g, 3e-4)
+
+
+@pytest.mark.parametrize("shared_graph", [False, True])
+@pytest.mark.parametrize("strategy", ["pallas_fused", "pallas"])
+def test_granule_ranges_vmap_over_partitions(strategy, shared_graph):
+    """vmap over three partitions whose granule ranges differ (one of
+    each layout) — the training step's batching — matches each partition
+    through the segment-sum path, in value and gradient. With a shared
+    graph only the parameters and features are mapped, and the kernels
+    take the arc list once per partition."""
+    parts = [_arc_layout(kind, 7 + i) for i, kind in
+             enumerate(("k8_like", "empty_tiles", "unsorted"))]
+    if shared_graph:
+        graph = parts[0][1][1:]
+        parts = [(p, (a[0], *graph)) for p, a in parts]
+    stack = lambda *xs: jnp.stack(xs)
+    params = jax.tree.map(stack, *[p for p, _ in parts])
+    args = jax.tree.map(stack, *[a for _, a in parts])
+    in_axes = (0, 0) + ((None,) * 4 if shared_graph else (0,) * 4)
+    if shared_graph:
+        args = (args[0], *graph)
+    with override(_range_config(strategy)):
+        (_, out), g = jax.vmap(_layer_loss(True), in_axes=in_axes)(params,
+                                                                   *args)
+    for i, (p, a) in enumerate(parts):
+        (_, ref), ref_g = _layer_loss(False)(p, *a)
+        _assert_close_tree(out[i], ref, 3e-5)
+        _assert_close_tree(jax.tree.map(lambda x: x[i], g), ref_g, 3e-4)
+
+
+# ---------------------------------------------------------------------------
 # autotune: resolution, candidates, cross-process cache determinism
 # ---------------------------------------------------------------------------
 def test_get_config_fallback_and_override():

@@ -179,6 +179,89 @@ def test_padding_contract_zero_weight_arcs_noop_on_both_paths():
                                    rtol=1e-5, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# streamed_pairs: the (node tile, edge granule) pairs the kernels stream
+# ---------------------------------------------------------------------------
+def _stream_cfg():
+    from repro.kernels.autotune import KernelConfig
+    return KernelConfig(strategy="pallas", node_tile=64, edge_block=32,
+                        stream=2)
+
+
+def _kernel_ranges(dst, n, cfg):
+    """The per-tile ranges the kernel computes, after the wrapper's
+    padding: ``(g0, g1, G)``."""
+    from repro.kernels.csr_aggregate import (edge_block_ranges,
+                                             tile_granule_ranges)
+    from repro.kernels.ops import _pad_graph
+    e = dst.shape[0]
+    h = jnp.zeros((n, 1), jnp.float32)
+    _, _, ed, _, _, n_pad = _pad_graph(h, jnp.zeros(e, jnp.int32),
+                                       jnp.asarray(dst, jnp.int32),
+                                       jnp.zeros(e), None, cfg)
+    tiles = n_pad // min(n_pad, cfg.node_tile)
+    lo, hi = edge_block_ranges(ed, cfg.edge_block)
+    g0, g1 = (np.asarray(r).reshape(-1)[:tiles]
+              for r in tile_granule_ranges(lo, hi, n_pad, ed.shape[0], cfg))
+    return g0, g1, ed.shape[0] // cfg.edge_granule
+
+
+def test_streamed_pairs_sorted_is_at_most_granules_plus_tiles():
+    """On a sorted list the ranges visit at most G + T - 1 pairs, and the
+    host count agrees with the ranges the kernel computes."""
+    from repro.kernels import streamed_pairs
+    cfg = _stream_cfg()
+    n, e = 1000, 5000
+    dst = np.sort(np.random.default_rng(0).integers(0, n, e))
+    streamed, dense = streamed_pairs(dst, n, cfg)
+    g0, g1, granules = _kernel_ranges(dst, n, cfg)
+    tiles = g0.size
+    assert dense == tiles * granules
+    assert streamed == int(np.maximum(g1 - g0 + 1, 0).sum())
+    assert streamed <= granules + tiles - 1
+
+
+def test_streamed_pairs_alignment_granule_spans_one_tile():
+    """Arcs into the first third of the rows, then assemble's padding
+    parked at row N-1, with E no multiple of the granule: the wrapper's
+    alignment arcs repeat the last destination, so the last granule meets
+    the last tile only (padding with row 0 made it span every tile)."""
+    from repro.kernels import streamed_pairs
+    cfg = _stream_cfg()
+    n, e = 1000, 5000 + 17
+    dst = np.full(e, n - 1)
+    dst[:4000] = np.sort(np.random.default_rng(1).integers(0, n // 3, 4000))
+    g0, g1, granules = _kernel_ranges(dst, n, cfg)
+    meets_last = np.flatnonzero((g0 <= granules - 1) & (g1 >= granules - 1))
+    assert meets_last.tolist() == [g0.size - 1]
+    # tiles past the arcs' third and before the last one stream at most
+    # the granule where the real arcs end and the parked ones begin
+    lonely = np.arange((n // 3) // 64 + 1, g0.size - 1)
+    assert np.all(g1[lonely] - g0[lonely] + 1 <= 1)
+    assert np.all(g0[lonely] >= 4000 // cfg.edge_granule)
+    streamed, _ = streamed_pairs(dst, n, cfg)
+    assert streamed <= granules + g0.size - 1
+
+
+def test_streamed_pairs_unsorted_matches_dense_coverage():
+    """An unsorted list whose first and last granules meet every tile
+    streams every (tile, granule) pair — today's coverage, so results
+    never depend on the order (values pinned in test_fused_layer.py)."""
+    from repro.kernels import streamed_pairs
+    cfg = _stream_cfg()
+    n, e = 1000, 79 * 64
+    spread = np.arange(0, n, 64)
+    rng = np.random.default_rng(2)
+    dst = np.concatenate([spread, rng.integers(0, n, e - 2 * spread.size),
+                          spread[::-1]])
+    streamed, dense = streamed_pairs(dst, n, cfg)
+    assert streamed == dense
+    # [k, E] lists count per partition and sum
+    both = streamed_pairs(np.stack([dst, np.sort(dst)]), n, cfg)
+    assert both == (dense + streamed_pairs(np.sort(dst), n, cfg)[0],
+                    2 * dense)
+
+
 def test_aggregate_mean_kernel_path_is_one_fused_call():
     """Degree normalization is fused into the kernel epilogue: the kernel
     path's jaxpr contains exactly one pallas_call (pallas strategy forced —

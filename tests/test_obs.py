@@ -407,3 +407,33 @@ def test_enabled_export_and_losses_unchanged_under_the_profiler(tmp_path,
     assert validate_trace(profiled[2]) == []
     first = next(args for name, args in plain[1] if name == "train.epoch")
     assert first["epoch"] == 0 and first["mode"] == "local"
+
+
+@pytest.mark.parametrize("enabled,strategy", [(True, "pallas_fused"),
+                                              (False, "pallas_fused"),
+                                              (True, "xla")])
+def test_stream_share_gauge_only_with_obs_on_and_a_pallas_config(
+        tiny_job, enabled, strategy):
+    """The training entry records ``kernels.stream_share`` from the host
+    batch while span collection is on and the resolved config runs the
+    Pallas kernels; the timed path (obs off) computes nothing."""
+    import dataclasses
+    from repro.gnn.train import _gather_and_upload
+    from repro.kernels import streamed_pairs
+    from repro.kernels.autotune import KernelConfig, override
+    ds, batch, cfg = tiny_job
+    cfg = dataclasses.replace(cfg, use_kernel=True)
+    kc = KernelConfig(strategy=strategy, node_tile=64, edge_block=32,
+                      stream=2)
+    if enabled:
+        obs.enable()
+    with override(kc):
+        _gather_and_upload(ds, batch, cfg, seed=0)
+    share = obs.gauge("kernels.stream_share").value
+    if not (enabled and kc.uses_pallas):
+        assert share is None
+        return
+    streamed, dense = streamed_pairs(batch.edge_dst, batch.node_ids.shape[1],
+                                     kc)
+    assert 0 < streamed <= dense
+    assert share == pytest.approx(streamed / dense)

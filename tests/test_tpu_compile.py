@@ -4,7 +4,8 @@ The TPU compiler refuses what interpret mode accepts — a kernel slice the
 tiling cannot hold, a 1-D block laid out differently from XLA, a program
 over the device's memory — so these tests compile the kernels and the
 local train step at the ogbn-arxiv k=8 ``repli`` shape (ROADMAP W1; n_pad
-79,344, e_pad 325,288, F=128) for one chip of a described ``v5e:2x2``.
+79,344, e_pad 325,288, F=128; the fused layer also at the hidden width
+F=256) for one chip of a described ``v5e:2x2``.
 
 The topology is described inside a module fixture, never while modules
 are imported: only the worker that runs this file loads the TPU library.
@@ -65,20 +66,21 @@ def _sds(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _layer_args(sharding):
-    return (_sds((N_PAD, F), sharding),
+def _layer_args(sharding, f):
+    return (_sds((N_PAD, f), sharding),
             _sds((E_PAD,), sharding, jnp.int32),
             _sds((E_PAD,), sharding, jnp.int32),
             _sds((E_PAD,), sharding),
             _sds((N_PAD,), sharding),
-            _sds((F, F), sharding),
-            _sds((F,), sharding))
+            _sds((f, f), sharding),
+            _sds((f,), sharding))
 
 
+@pytest.mark.parametrize("f", [F, 256])
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("strategy", ["pallas_fused", "pallas"])
 def test_fused_gcn_layer_compiles_for_v5e(one_chip, tpu_backend, strategy,
-                                          direction):
+                                          direction, f):
     cfg = KernelConfig(strategy=strategy)
 
     def layer(h, src, dst, w_edge, deg, w, b):
@@ -88,7 +90,7 @@ def test_fused_gcn_layer_compiles_for_v5e(one_chip, tpu_backend, strategy,
     if direction == "backward":
         fn = jax.grad(lambda *a: jnp.sum(layer(*a) ** 2),
                       argnums=(0, 3, 5, 6))
-    compiled = jax.jit(fn).lower(*_layer_args(one_chip)).compile()
+    compiled = jax.jit(fn).lower(*_layer_args(one_chip, f)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
