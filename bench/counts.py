@@ -1,64 +1,12 @@
-"""Work counted from shapes: the model's FLOPs per epoch, the least work
-of the aggregation kernels, and the collective bytes of a compiled step.
-
-Counts use each partition's real (unpadded) rows and arcs. ``layers`` is
-the list of (input width, output width) of the GCN layers.
+"""Work counted from shapes that every model shares: the least time of a
+list of kernel calls, and the collective bytes of a compiled step. A
+model's own counts (its FLOPs per epoch, its aggregation's least work) are
+in its model module, ``bench/models/<model>.py``.
 """
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Sequence, Tuple
-
-Widths = Sequence[Tuple[int, int]]
-
-
-def layer_widths(feature_dim: int, hidden_dim: int, embed_dim: int,
-                 num_layers: int) -> List[Tuple[int, int]]:
-    dims = [feature_dim] + [hidden_dim] * (num_layers - 1) + [embed_dim]
-    return list(zip(dims[:-1], dims[1:]))
-
-
-def model_flops_per_epoch(nodes: Sequence[int], arcs: Sequence[int],
-                          layers: Widths, num_classes: int) -> float:
-    """Multiply-adds (x2) one training epoch needs over all partitions:
-    the aggregations, the dense products and the head, forward and
-    backward. The first layer needs no input gradient (the features are
-    not trained) and no edge weight is trained, so neither is counted."""
-    total = 0.0
-    embed = layers[-1][1]
-    for n, e in zip(nodes, arcs):
-        for i, (fi, fo) in enumerate(layers):
-            total += 2 * e * fi + 2 * n * fi * fo          # forward
-            total += 2 * n * fi * fo                       # dW
-            if i > 0:
-                total += 2 * n * fi * fo + 2 * e * fi      # da, dh
-        total += 3 * 2 * n * embed * num_classes           # head f + b
-    return total
-
-
-def aggregation_least_work(nodes: Sequence[int], arcs: Sequence[int],
-                           layers: Widths, backward: bool
-                           ) -> List[Tuple[float, float]]:
-    """(FLOPs, bytes) of each aggregation kernel call one pass makes, summed
-    over partitions: the least any implementation must do.
-
-    Forward, per layer: the fused layer reads the input rows once
-    (N*F*4 B), the arcs (source, destination, weight: 12 B each) and writes
-    the output (N*F_out*4 B); it does 2*E*F for the aggregation and
-    2*N*F*F_out for the fused dense product. Backward (``backward``), per
-    layer after the first: the transposed aggregation of the input gradient,
-    2*E*F, reading and writing N*F*4 B and the arcs."""
-    calls = []
-    for i, (fi, fo) in enumerate(layers):
-        flops = sum(2 * e * fi + 2 * n * fi * fo for n, e in zip(nodes, arcs))
-        byts = sum(n * fi * 4 + e * 12 + n * fo * 4
-                   for n, e in zip(nodes, arcs))
-        calls.append((flops, byts))
-        if backward and i > 0:
-            calls.append((sum(2 * e * fi for e in arcs),
-                          sum(2 * n * fi * 4 + e * 12
-                              for n, e in zip(nodes, arcs))))
-    return calls
+from typing import Dict, Sequence, Tuple
 
 
 def least_seconds(calls: Sequence[Tuple[float, float]], peak_flops: float,

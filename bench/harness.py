@@ -7,6 +7,10 @@ configuration and a traffic mix. Each is a data file found by name:
 
 - ``configs[].file``: the model and the graph, with their source, cuts,
   assumptions and precision;
+- ``bench/models/<model>.py``, by the configuration's ``model`` key: the
+  program's configuration of that model, the plain reference's parameters
+  and forward pass, and its work counts (``bench/models/gcn.py`` says what
+  such a module holds);
 - ``bench/traffic/<traffic>.json``: how the job is laid out and driven
   (k, mode, scheme, partitioner, the partition seed, the epochs of one
   call, the steps the check follows);
@@ -32,6 +36,7 @@ import shutil
 import sys
 import time
 import traceback
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -87,13 +92,27 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         per_layer=_for_cell(spec["per_layer"], name), root=root)
 
 
-def load_reader(cell: Cell, metric: str) -> Callable[[Any], Optional[float]]:
-    path = os.path.join(cell.root, "bench", "metrics", metric + ".py")
+def _load_module(kind: str, path: str, name: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(cell: Cell, metric: str) -> Callable[[Any], Optional[float]]:
+    path = os.path.join(cell.root, "bench", "metrics", metric + ".py")
+    return _load_module("metric", path, metric).read
+
+
+def load_model(cell: Cell) -> ModuleType:
+    """The model module that the cell's configuration names."""
+    name = cell.config["model"]
+    path = os.path.join(cell.root, "bench", "models", name + ".py")
+    if not os.path.isfile(path):
+        raise KeyError(f"no model module {path} for the configuration's "
+                       f"model {name!r}")
+    return _load_module("model", path, name)
 
 
 def peaks_for(kind: str) -> Dict[str, Any]:
@@ -137,6 +156,7 @@ def use_compile_cache(cache: str) -> str:
 @dataclasses.dataclass
 class Job:
     cell: Cell
+    model: ModuleType             # bench/models/<model>.py
     seed: int
     ds: Any
     bundle: Any
@@ -147,7 +167,7 @@ class Job:
 def prepare(cell: Cell, seed: int, cache: str = CACHE) -> Job:
     """The dataset from the config's data seed, the partitions from the
     artifact store, and the training entry bound to this run's seed."""
-    from repro.gnn import GNNConfig, train_local, train_sync
+    from repro.gnn import train_local, train_sync
     from repro.launch.mesh import make_local_mesh
     from repro.pipeline import PartitionArtifactStore, get_dataset
     c, t = cell.config, cell.traffic
@@ -156,10 +176,8 @@ def prepare(cell: Cell, seed: int, cache: str = CACHE) -> Job:
     bundle = PartitionArtifactStore(os.path.join(cache, "parts")) \
         .load_or_compute(ds.graph, t["partitioner"], t["k"],
                          t["partition_seed"], t["scheme"], with_halo=sync)
-    gcfg = GNNConfig(kind=c["model"], feature_dim=int(ds.features.shape[1]),
-                     hidden_dim=c["hidden_dim"], embed_dim=c["embed_dim"],
-                     num_layers=c["num_layers"], dropout=c["dropout"],
-                     use_kernel=c["use_kernel"])
+    model = load_model(cell)
+    gcfg = model.program_config(c, int(ds.features.shape[1]))
     mesh = make_local_mesh()
     if sync:
         call = functools.partial(train_sync, ds, bundle.batch, bundle.halo,
@@ -169,8 +187,8 @@ def prepare(cell: Cell, seed: int, cache: str = CACHE) -> Job:
                                  lr=c["lr"], seed=seed, mesh=mesh)
     else:
         raise ValueError(f"traffic mode {t['mode']!r} is not driven here")
-    return Job(cell=cell, seed=seed, ds=ds, bundle=bundle, mesh=mesh,
-               call=call)
+    return Job(cell=cell, model=model, seed=seed, ds=ds, bundle=bundle,
+               mesh=mesh, call=call)
 
 
 @dataclasses.dataclass
@@ -278,11 +296,9 @@ def reference_run(job: Job, layout, steps: int, **control):
     tensors = reference.device_tensors(layout, np.asarray(ds.features),
                                        np.asarray(ds.labels),
                                        np.asarray(ds.train_mask), sharding)
-    m = reference.Model(feature_dim=int(ds.features.shape[1]),
-                        hidden_dim=c["hidden_dim"], embed_dim=c["embed_dim"],
-                        num_layers=c["num_layers"],
+    m = reference.Model(module=job.model, config=c,
+                        feature_dim=int(ds.features.shape[1]),
                         num_classes=int(ds.num_classes),
-                        dropout=c["dropout"], lr=c["lr"],
                         sync=t["mode"] == "sync")
     ar = dataclasses.replace(reference.Arithmetic.of(c["precision"]),
                              **control)
@@ -391,6 +407,7 @@ def judge(numbers: Dict[str, Any], limits: Dict[str, Any]):
 class Context:
     """What a per-layer reader can read."""
     cell: Cell
+    model: ModuleType             # the work counts of bench/models/<model>.py
     peaks: Dict[str, Any]
     chips: int
     window: Window
@@ -450,7 +467,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         from bench import trace as trace_mod
         tr = trace_mod.load(trace_mod.find_xplane(trace_dir))
         lo, hi = trace_mod.window(tr)
-        ctx = Context(cell=cell, peaks=peaks_for(d0.device_kind),
+        ctx = Context(cell=cell, model=job.model,
+                      peaks=peaks_for(d0.device_kind),
                       chips=cell.chips, window=win, layout=layout, trace=tr,
                       lo=lo, hi=hi, hlo_text=hlo_text)
         metrics = {}
@@ -465,9 +483,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                             "idle_gaps": trace_mod.idle_gaps(tr, lo, hi)}
         shutil.rmtree(trace_dir, ignore_errors=True)
     else:
+        # ``<quantity>.<part>`` reports the quantity in the cells that the
+        # entry lists, under a bound of its own
         values = {"epochs_per_s": win.epochs / win.seconds,
                   "peak_hbm_gib": peak / 2 ** 30, "setup_s": setup_s}
-        out["metrics"] = {m["name"]: {"value": values[m["name"]],
+        out["metrics"] = {m["name"]: {"value": values[m["name"].split(".")[0]],
                                       "unit": m["unit"]}
                           for m in cell.end_to_end}
     out["device"] = device

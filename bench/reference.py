@@ -1,4 +1,4 @@
-"""Plain reference of the timed training job: k GCNs trained on their
+"""Plain reference of the timed training job: k GNNs trained on their
 partition subgraphs, written from the published semantics in straight
 ``jax.numpy``. It imports nothing of the program under test.
 
@@ -11,10 +11,13 @@ builds each partition's subgraph itself:
   owned node. It keeps every arc whose destination it owns. Rows are padded
   to the largest partition, rounded up to 8, so that the dropout masks,
   which are drawn at the padded shape, are the same bits as the program's.
-- a GCN layer is ``act(mean_{u -> v} w_uv h_u @ W + b)`` with the mean over
-  the arcs kept (in-degree counted in arcs); padding rows are held at zero;
-  dropout (rate ``dropout``) follows every layer but the last.
-- a linear head and the masked softmax cross-entropy over owned train nodes.
+- the model's layers, parameters and head are its model module's
+  (``bench/models/<model>.py``, named by the configuration's ``model``);
+  this file gives every model the pieces they share: the halo refresh
+  (:func:`refresh`), dropout (:func:`dropout`), the dense products in the
+  stated arithmetic (:func:`product`) and the linear head
+  (:func:`head_logits`).
+- the masked softmax cross-entropy over owned train nodes.
 - ``sync`` mode refreshes every halo row from its owner partition before
   every layer, and the gradient flows back through that refresh: the loss
   that is differentiated is the sum of the partitions' losses.
@@ -25,7 +28,8 @@ builds each partition's subgraph itself:
 
 Random draws follow the program's published key schedule: parameters from
 ``PRNGKey(seed)`` split per partition, and at epoch e the dropout keys
-``split(fold_in(PRNGKey(seed), e), k)``.
+``split(fold_in(PRNGKey(seed), e), k)``, each split again before every
+layer's mask.
 
 The arithmetic is the one the configuration states (:class:`Arithmetic`):
 the storage type, and the precision of the body's dense products and of the
@@ -36,7 +40,8 @@ products' operands, and the cotangents through them, to that type first.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
@@ -132,77 +137,59 @@ def build_layout(indptr: np.ndarray, indices: np.ndarray,
 
 @dataclasses.dataclass(frozen=True)
 class Model:
+    """What a model module's reference is built from."""
+    module: ModuleType   # bench/models/<model>.py
+    config: Mapping      # the configuration file
     feature_dim: int
-    hidden_dim: int
-    embed_dim: int
-    num_layers: int
     num_classes: int
-    dropout: float
-    lr: float
     sync: bool           # halo rows refreshed from their owners
 
+    @property
+    def dropout(self) -> float:
+        return self.config["dropout"]
 
-def init_params(seed: int, m: Model, k: int):
-    """k stacked GCN + head parameter sets from ``PRNGKey(seed)``."""
-    dims = ([m.feature_dim] + [m.hidden_dim] * (m.num_layers - 1)
-            + [m.embed_dim])
-
-    def one(key):
-        kb, kh = jax.random.split(key)
-        lkeys = jax.random.split(kb, m.num_layers)
-        layers = [{"w": jax.random.normal(lkeys[i], (dims[i], dims[i + 1]),
-                                          jnp.float32)
-                   * jnp.sqrt(2.0 / dims[i]),
-                   "b": jnp.zeros((dims[i + 1],), jnp.float32)}
-                  for i in range(m.num_layers)]
-        head = {"w": jax.random.normal(kh, (m.embed_dim, m.num_classes))
-                * jnp.sqrt(2.0 / m.embed_dim),
-                "b": jnp.zeros((m.num_classes,))}
-        return {"body": {"layers": layers}, "head": head}
-    return jax.jit(jax.vmap(one))(jax.random.split(jax.random.PRNGKey(seed),
-                                                   k))
+    @property
+    def lr(self) -> float:
+        return self.config["lr"]
 
 
-def _forward(params, m: Model, t, keys, ar: Arithmetic):
-    """Stacked forward over all k partitions: (embeddings, logits)."""
-    k, n_pad = t["mask"].shape
-    mask = t["mask"][..., None]
-    h = t["x"] * mask
+def refresh(h, t):
+    """Every halo row replaced by its owner partition's copy
+    (``h``: [k, n_pad, F])."""
+    k, n_pad = h.shape[:2]
+    flat = h.reshape(k * n_pad, -1)
+    return flat[t["refresh"]].reshape(k, n_pad, -1)
 
-    def mm(spec, a, b, precision):
-        if ar.product_dtype is not None:
-            a, b = (v.astype(ar.product_dtype).astype(v.dtype)
-                    for v in (a, b))
-        return jnp.einsum(spec, a, b,
-                          precision=jax.lax.Precision(precision))
-    n_layers = len(params["body"]["layers"])
-    for i, lp in enumerate(params["body"]["layers"]):
-        last = i == n_layers - 1
-        if m.sync:
-            flat = h.reshape(k * n_pad, -1)
-            h = flat[t["refresh"]].reshape(k, n_pad, -1)
 
-        def agg_one(h1, s, d, w, deg):
-            tot = jax.ops.segment_sum(h1[s] * w[:, None], d,
-                                      num_segments=n_pad)
-            return tot / jnp.maximum(deg, 1.0)[:, None]
-        agg = jax.vmap(agg_one)(h, t["src"], t["dst"], t["w"], t["deg"])
-        z = mm("knf,kfo->kno", agg, lp["w"], ar.body) + lp["b"][:, None, :]
-        h = z if last else jax.nn.relu(z)
-        h = h * mask
-        if keys is not None and m.dropout > 0 and not last:
-            split = jax.vmap(jax.random.split)(keys)
-            keys, sub = split[:, 0], split[:, 1]
-            keep = jax.vmap(lambda s: jax.random.bernoulli(
-                s, 1 - m.dropout, (n_pad, h.shape[-1])))(sub)
-            h = jnp.where(keep, h / (1 - m.dropout), 0.0)
-    head = params["head"]
-    logits = mm("kne,kec->knc", h, head["w"], ar.head) + head["b"][:, None, :]
-    return h, logits
+def dropout(h, keys, rate: float):
+    """Inverted dropout of every partition's rows, each with its own key;
+    returns (h, the keys for the next layer). With no keys (the embedding
+    pass) or no rate, ``h`` as it is."""
+    if keys is None or rate <= 0:
+        return h, keys
+    split = jax.vmap(jax.random.split)(keys)
+    keys, sub = split[:, 0], split[:, 1]
+    keep = jax.vmap(lambda s: jax.random.bernoulli(
+        s, 1 - rate, (h.shape[1], h.shape[-1])))(sub)
+    return jnp.where(keep, h / (1 - rate), 0.0), keys
+
+
+def product(spec: str, a, b, precision: str, ar: "Arithmetic"):
+    """A dense product at ``precision``, its operands first rounded to
+    ``ar.product_dtype`` where a control sets one."""
+    if ar.product_dtype is not None:
+        a, b = (v.astype(ar.product_dtype).astype(v.dtype) for v in (a, b))
+    return jnp.einsum(spec, a, b, precision=jax.lax.Precision(precision))
+
+
+def head_logits(head, h, ar: "Arithmetic"):
+    """The per-partition linear head on the embeddings."""
+    return product("kne,kec->knc", h, head["w"], ar.head, ar) \
+        + head["b"][:, None, :]
 
 
 def _losses(params, m: Model, t, keys, ar: Arithmetic):
-    _, logits = _forward(params, m, t, keys, ar)
+    _, logits = m.module.forward(params, m, t, keys, ar)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, t["y"][..., None], axis=-1)[..., 0]
     tm = t["train"]
@@ -270,7 +257,7 @@ def train(layout: Layout, t: Dict, m: Model, seed: int, steps: int,
     arithmetic ``ar``; with ``sharding`` the partitions are spread over the
     devices along their leading axis."""
     k = layout.k
-    params = init_params(seed, m, k)
+    params = m.module.init_params(seed, m, k)
     params0 = jax.tree.map(np.asarray, params)
     store = jnp.dtype(ar.dtype)
     if store != jnp.float32:
@@ -282,7 +269,7 @@ def train(layout: Layout, t: Dict, m: Model, seed: int, steps: int,
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(jnp.zeros_like, params)
     key = jax.random.PRNGKey(seed)
-    embed = jax.jit(lambda p, t: _forward(p, m, t, None, ar)[0])
+    embed = jax.jit(lambda p, t: m.module.forward(p, m, t, None, ar)[0])
 
     def table(p):
         return pool(np.asarray(embed(p, t), np.float32), layout, num_nodes)

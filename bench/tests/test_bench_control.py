@@ -11,7 +11,7 @@ import pytest
 import tinycell
 from bench import harness
 
-LIMITS = ["arxiv-gcn-pallas.k8-local"]
+LIMITS = ["arxiv-gcn-pallas.k8-local", "arxiv-gcn.k4-sync"]
 
 
 @pytest.fixture(scope="module")

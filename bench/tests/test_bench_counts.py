@@ -1,16 +1,18 @@
-"""Work counted from shapes (bench/counts.py) against hand counts at a
-tiny shape: one partition of 10 nodes and 20 arcs, layers 4->6->6->5,
-3 classes."""
+"""Work counted from shapes (bench/counts.py and the GCN's model module,
+bench/models/gcn.py) against hand counts at a tiny shape: one partition of
+10 nodes and 20 arcs, layers 4->6->6->5, 3 classes."""
 import pytest
 
 import tinycell  # noqa: F401  (puts the checkout on sys.path)
 from bench import counts
+from bench.models import gcn
 
+CONFIG = {"feature_dim": 4, "hidden_dim": 6, "embed_dim": 5, "num_layers": 3}
 LAYERS = [(4, 6), (6, 6), (6, 5)]
 
 
 def test_layer_widths():
-    assert counts.layer_widths(4, 6, 5, 3) == LAYERS
+    assert gcn.layer_widths(CONFIG, 4) == LAYERS
 
 
 def test_model_flops_per_epoch():
@@ -18,17 +20,16 @@ def test_model_flops_per_epoch():
     # layer 1: forward 240 + 720, dW 720, da 720, dh 240
     # layer 2: forward 240 + 600, dW 600, da 600, dh 240
     # head: forward, dW and d(embedding), 2*10*5*3 each
-    assert counts.model_flops_per_epoch([10], [20], LAYERS, 3) == (
+    assert gcn.flops_per_epoch(CONFIG, [10], [20], 3) == (
         1120 + 2640 + 2280 + 900)
     # partitions add up
-    assert counts.model_flops_per_epoch([10, 10], [20, 20], LAYERS, 3) == (
-        2 * 6940)
+    assert gcn.flops_per_epoch(CONFIG, [10, 10], [20, 20], 3) == 2 * 6940
 
 
 def test_aggregation_least_work():
-    fwd = counts.aggregation_least_work([10], [20], LAYERS, backward=False)
+    fwd = gcn.aggregation_least_work(CONFIG, [10], [20], backward=False)
     assert fwd == [(640, 640), (960, 720), (840, 680)]
-    both = counts.aggregation_least_work([10], [20], LAYERS, backward=True)
+    both = gcn.aggregation_least_work(CONFIG, [10], [20], backward=True)
     assert both == [(640, 640), (960, 720), (240, 720), (840, 680),
                     (240, 720)]
 

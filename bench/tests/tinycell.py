@@ -1,6 +1,6 @@
 """A tiny cell written into a scratch checkout, for runs of the harness on
-the CPU: the real BENCHMARK.json's metrics and readers, a 600-node graph
-and a 16-wide GCN."""
+the CPU: the real BENCHMARK.json's metrics, readers and model modules, a
+600-node graph and a 16-wide GCN."""
 from __future__ import annotations
 
 import json
@@ -25,9 +25,11 @@ TINY = {"dataset": "arxiv-like",
 
 
 def write_root(tmp: str, mode: str = "local", use_kernel: bool = False,
-               k: int = 4, limits_from: str = "arxiv-gcn-pallas.k8-local") -> str:
+               k: int = 4, limits_from: str = "arxiv-gcn-pallas.k8-local",
+               config: dict = None) -> str:
     """A checkout under ``tmp`` holding one cell named ``tiny.<mode>``;
-    returns the cell's name. Limits are the real cell's."""
+    returns the cell's name. Limits are the real cell's; ``config`` adds
+    to or overrides the tiny configuration's keys."""
     spec = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     cell = f"tiny.{mode}"
     spec["configs"] = [{"name": "tiny", "source": "test",
@@ -35,17 +37,20 @@ def write_root(tmp: str, mode: str = "local", use_kernel: bool = False,
                         "why": "test"}]
     spec["workloads"] = [{"name": cell, "config": "tiny", "traffic": mode,
                           "chips": 1, "why": "test"}]
-    for m in spec["per_layer"]:
+    for m in spec["end_to_end"] + spec["per_layer"]:
         m.pop("workloads", None)
     bench = os.path.join(tmp, "bench")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for sub in ("metrics", "models"):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench, sub),
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     json.dump(spec, open(os.path.join(tmp, "BENCHMARK.json"), "w"))
     precision = {"storage": "float32", "head_product": "default",
                  "body_products": "highest" if use_kernel else "default"}
-    json.dump(dict(TINY, use_kernel=use_kernel, precision=precision),
+    json.dump({**TINY, "use_kernel": use_kernel, "precision": precision,
+               **(config or {})},
               open(os.path.join(bench, "configs", "tiny.json"), "w"))
     json.dump({"k": k, "mode": mode, "scheme": "repli",
                "partitioner": "leiden_fusion", "partition_seed": 0,
