@@ -1,38 +1,52 @@
-"""Multi-layer GNN (GCN / GraphSAGE) + MLP classifier head.
+"""Multi-layer GNN (GCN / GraphSAGE / GAT) + MLP classifier head.
 
 The GNN body produces node *embeddings* (paper: embeddings from local
 training are pooled and an MLP classifier is trained on them)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .layers import (gcn_layer, init_gcn_layer, init_sage_layer, sage_layer)
+from .layers import layer_for
 
 PyTree = Any
 
 
 @dataclasses.dataclass(frozen=True)
 class GNNConfig:
-    kind: str = "gcn"              # "gcn" | "sage"
+    kind: str = "gcn"              # "gcn" | "sage" | "gat"
     feature_dim: int = 128
-    hidden_dim: int = 256
+    hidden_dim: int = 256          # per head for "gat"
     embed_dim: int = 256           # output embedding size
     num_layers: int = 3
     dropout: float = 0.5
     use_kernel: bool = False       # route aggregation through Pallas kernel
+    heads: int = 1                 # "gat": hidden layers concatenate their
+                                   # heads, the last averages them
+
+    def __post_init__(self):
+        if self.heads != 1 and self.kind != "gat":
+            raise ValueError(f"heads={self.heads}: only a 'gat' layer has "
+                             f"more than one head, not {self.kind!r}")
+
+
+def layer_widths(cfg: GNNConfig) -> List[Tuple[int, int]]:
+    """(input width, output width of one head) of each layer; a hidden
+    layer's output, the next one's input, is its heads concatenated."""
+    hidden = [cfg.hidden_dim] * (cfg.num_layers - 1)
+    ins = [cfg.feature_dim] + [cfg.heads * d for d in hidden]
+    return list(zip(ins, hidden + [cfg.embed_dim]))
 
 
 def init_gnn(key, cfg: GNNConfig) -> PyTree:
-    dims = ([cfg.feature_dim] + [cfg.hidden_dim] * (cfg.num_layers - 1)
-            + [cfg.embed_dim])
     keys = jax.random.split(key, cfg.num_layers)
-    init = init_gcn_layer if cfg.kind == "gcn" else init_sage_layer
-    return {"layers": [init(keys[i], dims[i], dims[i + 1])
-                       for i in range(cfg.num_layers)]}
+    _, init = layer_for(cfg.kind)
+    return {"layers": [init(keys[i], f_in, f_out, heads=cfg.heads,
+                            concat=i < cfg.num_layers - 1)
+                       for i, (f_in, f_out) in enumerate(layer_widths(cfg))]}
 
 
 def gnn_forward(params: PyTree, cfg: GNNConfig, features: jnp.ndarray,
@@ -40,7 +54,7 @@ def gnn_forward(params: PyTree, cfg: GNNConfig, features: jnp.ndarray,
                 node_mask: Optional[jnp.ndarray] = None,
                 dropout_key: Optional[jax.Array] = None) -> jnp.ndarray:
     """Run the GNN body; returns [N, embed_dim] embeddings."""
-    layer = gcn_layer if cfg.kind == "gcn" else sage_layer
+    layer, _ = layer_for(cfg.kind)
     h = features
     if node_mask is not None:
         h = h * node_mask[:, None]          # zero padded rows

@@ -40,7 +40,7 @@ from repro import obs
 from repro.core import NodeDataset, PartitionBatch, HaloExchangeSpec
 from repro.optim import OptState, adamw_init, adamw_update
 from .model import (GNNConfig, gnn_forward, head_logits, init_gnn, init_mlp,
-                    mlp_forward, sigmoid_bce, softmax_xent)
+                    layer_widths, mlp_forward, sigmoid_bce, softmax_xent)
 
 PyTree = Any
 
@@ -225,14 +225,106 @@ def _tensors_dict(pt: PartitionTensors) -> Dict[str, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
+# Partition groups: how many partitions the local step holds at once
+# ---------------------------------------------------------------------------
+# Share of the device's ``bytes_limit`` the chooser leaves free: room for
+# what the estimate does not count (the executables' code, the runtime's
+# own buffers, the embedding pass's table) and for its error against the
+# compiler's count (tests/test_tpu_compile.py bounds that error).
+GROUP_MARGIN = 0.15
+
+
+# Buffers the step's backward keeps live at its peak, per partition in
+# flight, as the TPU compiler's buffer assignment for a v5e counts them at
+# the ogbn-arxiv k=8 shapes: [N, heads * width] activations per layer, and
+# [E, aggregated width] row gathers. A GAT layer keeps more of both: its
+# arc weights are learned, so its backward gathers the rows twice more for
+# the attention's row dot (``edge_dot``), and the heads' padded and
+# transposed copies of z sit beside z.
+_LIVE = {"gat": (4, 5)}       # (activations, gathers); others (2, 2)
+
+
+def step_bytes_estimate(cfg: GNNConfig, k: int, n_pad: int, e_pad: int,
+                        g: int, num_classes: int) -> int:
+    """Device bytes of the local step that trains k partitions g at a
+    time: what all k keep (their tensors, parameters and AdamW state, each
+    an input and an output of the step) and what g partitions in flight
+    hold (each layer's input and live activations, and the live row
+    gathers of the widest aggregation, :data:`_LIVE`). Float32; the
+    aggregated width is padded to the kernels' 128 lanes. Within 1.5x of
+    the TPU compiler's count for the ogbn-arxiv k=8 GCN and GAT steps
+    (tests/test_tpu_compile.py)."""
+    lanes = lambda f: -(-f // 128) * 128
+    acts, gathers = _LIVE.get(cfg.kind, (2, 2))
+    widths = layer_widths(cfg)
+    n_params = sum(f_in * cfg.heads * f_out for f_in, f_out in widths)
+    n_params += cfg.embed_dim * num_classes
+    keep = n_pad * (cfg.feature_dim + 4) + 3 * e_pad + 6 * n_params
+    flight = sum(n_pad * (f_in + acts * cfg.heads * f_out)
+                 for f_in, f_out in widths)
+    agg = max(cfg.heads * lanes(f_out) if cfg.kind == "gat" else lanes(f_in)
+              for f_in, f_out in widths)
+    flight += gathers * e_pad * agg
+    return 4 * (k * keep + g * flight)
+
+
+def choose_partition_group(cfg: GNNConfig, k: int, n_pad: int, e_pad: int,
+                           num_classes: int, bytes_limit: Optional[int]
+                           ) -> Tuple[int, int]:
+    """``(g, estimate)``: the largest divisor g of k whose
+    :func:`step_bytes_estimate` fits under ``bytes_limit`` less
+    :data:`GROUP_MARGIN`, and that estimate. With no limit known (a CPU
+    backend) g is k; where no divisor fits, 1."""
+    divisors = [g for g in range(k, 0, -1) if k % g == 0]
+    if bytes_limit is not None:
+        budget = bytes_limit * (1.0 - GROUP_MARGIN)
+        for g in divisors:
+            est = step_bytes_estimate(cfg, k, n_pad, e_pad, g, num_classes)
+            if est <= budget:
+                return g, est
+    g = k if bytes_limit is None else 1
+    return g, step_bytes_estimate(cfg, k, n_pad, e_pad, g, num_classes)
+
+
+def _bytes_limit(mesh: Optional[Mesh]) -> Optional[int]:
+    """The memory the local step's device may use, where it runs on one
+    device and the backend reports it; None otherwise (the CPU reports
+    none, and a k axis sharded over several devices keeps its vmap)."""
+    devices = (list(mesh.devices.flat) if mesh is not None
+               else jax.local_devices()[:1])
+    if len(devices) != 1:
+        return None
+    stats = devices[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def _over_partitions(fn: Callable, group: Optional[int]) -> Callable:
+    """``fn`` of one partition mapped over a leading partition axis of k,
+    ``group`` partitions at a time: a ``vmap`` inside a ``lax.map`` over
+    k / group groups, and the plain ``vmap`` where the group is all k (or
+    None). The mapped function keeps ``fn``'s name and signature, which
+    name the jitted program and its parameters."""
+    @functools.wraps(fn)
+    def mapped(*args):
+        k = jax.tree.leaves(args)[0].shape[0]
+        if group is None or group >= k:
+            return jax.vmap(fn)(*args)
+        return jax.lax.map(lambda a: fn(*a), args, batch_size=group)
+    return mapped
+
+
+# ---------------------------------------------------------------------------
 # LOCAL training (the paper's scheme — zero collectives)
 # ---------------------------------------------------------------------------
 def make_local_train_step(cfg: GNNConfig, multilabel: bool, lr: float = 1e-2,
-                          per_partition: bool = False) -> Callable:
+                          per_partition: bool = False,
+                          group: Optional[int] = None) -> Callable:
     """Returns jit-able step(params, opt, tensors, key) -> (params, opt, loss).
 
     All arrays carry a leading k axis; the step is a pure vmap — sharding the
-    k axis over `data` makes it fully local per device. With
+    k axis over `data` makes it fully local per device. With ``group`` < k
+    it takes the partitions ``group`` at a time (:func:`_over_partitions`;
+    :func:`choose_partition_group` sizes it to the device). With
     ``per_partition=True`` the un-vmapped single-partition step is returned
     instead (no leading k axis) — the low-memory sequential path trains one
     partition at a time with it, and since local-mode partitions never
@@ -247,7 +339,7 @@ def make_local_train_step(cfg: GNNConfig, multilabel: bool, lr: float = 1e-2,
         return one_step
 
     def step(params, opt, tensors, keys):
-        return jax.vmap(one_step)(params, opt, tensors, keys)
+        return _over_partitions(one_step, group)(params, opt, tensors, keys)
     return step
 
 
@@ -284,7 +376,12 @@ def train_local(ds: NodeDataset, batch: PartitionBatch, cfg: GNNConfig,
     with obs.span("train.call", mode="local", k=k, epochs=epochs):
         pt, key, params, opt, tensors = _gather_and_upload(ds, batch, cfg,
                                                            seed)
-        step = make_local_train_step(cfg, ds.multilabel, lr)
+        group, estimate = choose_partition_group(
+            cfg, k, batch.n_pad, batch.e_pad, ds.num_classes,
+            _bytes_limit(mesh))
+        obs.gauge("train.partition_group").set(group)
+        obs.gauge("train.step_bytes_estimate").set(estimate)
+        step = make_local_train_step(cfg, ds.multilabel, lr, group=group)
         if mesh is not None:
             shard = NamedSharding(mesh, P("data"))
             step = jax.jit(step, in_shardings=(shard, shard, shard, shard),
@@ -301,7 +398,7 @@ def train_local(ds: NodeDataset, batch: PartitionBatch, cfg: GNNConfig,
             return loss
 
         _run_epochs(epochs, key, k, "local", run_epoch)
-        return _embed_and_pool(params, integrate, _local_embed(cfg),
+        return _embed_and_pool(params, integrate, _local_embed(cfg, group),
                                tensors, k, pt, ds.graph.n, cfg.embed_dim)
 
 
@@ -320,6 +417,9 @@ def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
     with obs.span("train.gather"):
         pt = gather_partition_tensors(ds, batch)
     k = batch.k
+    obs.gauge("train.partition_group").set(1)
+    obs.gauge("train.step_bytes_estimate").set(step_bytes_estimate(
+        cfg, 1, batch.n_pad, batch.e_pad, 1, ds.num_classes))
     np_tensors = _tensors_dict(pt)
     key = jax.random.PRNGKey(seed)
     params = init_partition_models(key, cfg, ds.num_classes, k)
@@ -362,10 +462,11 @@ def _train_local_sequential(ds: NodeDataset, batch: PartitionBatch,
                                        cfg.embed_dim)
 
 
-def _local_embed(cfg: GNNConfig):
+def _local_embed(cfg: GNNConfig, group: Optional[int] = None):
     """The local embedding pass, jitted: ``(params, tensors) -> [k, N_pad,
-    E]``."""
-    return jax.jit(jax.vmap(lambda p, t: _forward_one(p, cfg, t)[0]))
+    E]``, ``group`` partitions at a time."""
+    return jax.jit(_over_partitions(lambda p, t: _forward_one(p, cfg, t)[0],
+                                    group))
 
 
 def compute_embeddings(params, cfg: GNNConfig, tensors) -> jnp.ndarray:
@@ -472,8 +573,8 @@ def make_halo_forward(cfg: GNNConfig, halo: HaloExchangeSpec,
         h = h.at[safe].set(jnp.where(valid, cache[safe], h[safe]))
         return h
 
-    from .layers import gcn_layer, sage_layer
-    layer_fn = gcn_layer if cfg.kind == "gcn" else sage_layer
+    from .layers import layer_for
+    layer_fn, _ = layer_for(cfg.kind)
 
     def forward(params, t, my_idx, dropout_key=None, caches=None,
                 refresh_mode: str = "exchange"):
@@ -638,8 +739,7 @@ def stale_bytes_per_epoch(exchange_bytes: int, epochs: int,
 
 def _stale_cache_shapes(cfg: GNNConfig, n_pad: int) -> List[Tuple[int, int]]:
     """Per-layer cache shapes: the layer-i *input* activations [N_pad, F_i]."""
-    dims = [cfg.feature_dim] + [cfg.hidden_dim] * (cfg.num_layers - 1)
-    return [(n_pad, d) for d in dims]
+    return [(n_pad, f_in) for f_in, _ in layer_widths(cfg)]
 
 
 def make_stale_train_steps(cfg: GNNConfig, halo: HaloExchangeSpec,

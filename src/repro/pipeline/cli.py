@@ -79,7 +79,12 @@ def _build_parser() -> argparse.ArgumentParser:
                      help="aggregate the k per-partition models before "
                           "embedding assembly: model_avg parameter-averages "
                           "(arxiv 2305.09887), ensemble averages embeddings")
-    run.add_argument("--model", default="gcn", choices=["gcn", "sage"])
+    run.add_argument("--model", default="gcn",
+                     choices=["gcn", "sage", "gat"])
+    run.add_argument("--heads", type=int, default=1,
+                     help="attention heads a GAT layer (--model gat): hidden "
+                          "layers concatenate them, the last averages them; "
+                          "--hidden-dim is the width of one head")
     run.add_argument("--use-kernel", action="store_true",
                      help="route GNN layers through the autotuned kernel "
                           "dispatcher (fused Pallas layer on TPU, XLA "
@@ -154,7 +159,7 @@ def _cmd_run(args: argparse.Namespace) -> int:
     cfg = PipelineConfig(
         dataset=args.dataset, method=args.method, k=args.k, seed=args.seed,
         scheme=args.scheme, mode=args.mode, sync_period=args.sync_period,
-        integrate=args.integrate, model=args.model,
+        integrate=args.integrate, model=args.model, heads=args.heads,
         use_kernel=args.use_kernel,
         kernel_autotune=args.kernel_autotune,
         hidden_dim=args.hidden_dim, embed_dim=args.embed_dim,

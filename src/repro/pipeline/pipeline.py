@@ -78,7 +78,8 @@ class PipelineConfig:
                                     # epochs (1 ≡ sync; 0 = never ≡ local)
     integrate: str = "none"         # "none" | "model_avg" | "ensemble" —
                                     # aggregate the k models pre-assembly
-    model: str = "gcn"              # "gcn" | "sage"
+    model: str = "gcn"              # "gcn" | "sage" | "gat"
+    heads: int = 1                  # "gat": attention heads a layer
     use_kernel: bool = False        # route GNN layers through the kernel
                                     # dispatcher (DESIGN.md §3/§11/§14);
                                     # differentiable, so every training
@@ -128,7 +129,9 @@ class PipelineReport:
     partition_cache_hit: bool
     batch_cache_hit: bool
     artifact_paths: Dict[str, Optional[str]]
-    shapes: Dict[str, int]           # k, n_pad, e_pad
+    shapes: Dict[str, int]           # k, n_pad, e_pad; local mode adds the
+                                     # partition group and its step-memory
+                                     # estimate (bytes)
     collectives: Dict[str, int]      # collective_bytes() of the train step
     accuracy: Dict[str, float]       # train/val/test (empty if skipped)
     timings: Dict[str, float]
@@ -172,9 +175,17 @@ class PipelineReport:
         if mode == "stale":
             period = c.get("sync_period", 0)
             mode = f"stale(period={period if period else '∞'})"
-        lines.append(f"  training     mode={mode} model={c['model']} "
+        model = c["model"]
+        if c.get("heads", 1) != 1:
+            model += f"(heads={c['heads']})"
+        group = ""
+        if "partition_group" in self.shapes:
+            group = (f" partition_group={self.shapes['partition_group']} "
+                     f"(~{self.shapes['step_bytes_estimate'] / 2**30:.2f} "
+                     f"GiB a step)")
+        lines.append(f"  training     mode={mode} model={model} "
                      f"layers={c['num_layers']} epochs={c['epochs']} "
-                     f"aggregation={agg} devices={self.num_devices}")
+                     f"aggregation={agg} devices={self.num_devices}{group}")
         if c.get("integrate", "none") != "none":
             lines.append(f"  integration  {c['integrate']} over k={c['k']} "
                          f"partition models (pre-assembly)")
@@ -318,15 +329,20 @@ class Pipeline:
                                 embed_dim=cfg.embed_dim,
                                 num_layers=cfg.num_layers,
                                 dropout=cfg.dropout,
-                                use_kernel=cfg.use_kernel)
-            # kernel config resolution/tuning: one bucket per distinct layer
-            # input width at this run's padded partition shape (DESIGN.md §14)
+                                use_kernel=cfg.use_kernel,
+                                heads=cfg.heads)
+            # kernel config resolution/tuning: one bucket per distinct
+            # aggregated width at this run's padded partition shape
+            # (DESIGN.md §14): a GCN/SAGE layer aggregates its input, a GAT
+            # layer each head's projected rows
             kernel_info: Optional[Dict[str, Any]] = None
             if cfg.use_kernel:
                 from repro.kernels.autotune import autotune as tune_bucket
                 from repro.kernels.autotune import get_config
                 n_pad, e_pad = bundle.batch.n_pad, bundle.batch.e_pad
-                widths = sorted({gnn_cfg.feature_dim, gnn_cfg.hidden_dim})
+                widths = sorted({gnn_cfg.hidden_dim, gnn_cfg.embed_dim}
+                                if gnn_cfg.kind == "gat" else
+                                {gnn_cfg.feature_dim, gnn_cfg.hidden_dim})
                 if cfg.kernel_autotune:
                     with _stage_span(timings, "kernel_autotune",
                                      "pipeline.kernel_autotune",
@@ -365,6 +381,11 @@ class Pipeline:
                         sync_period=cfg.sync_period, hlo_out=hlo_out,
                         integrate=cfg.integrate)
         obs.sample_memory_now()
+        shapes = {"k": bundle.batch.k, "n_pad": bundle.batch.n_pad,
+                  "e_pad": bundle.batch.e_pad}
+        if cfg.mode == "local":
+            for name in ("partition_group", "step_bytes_estimate"):
+                shapes[name] = int(obs.gauge(f"train.{name}").value)
 
         collectives: Dict[str, int] = {}
         if hlo_out:
@@ -440,8 +461,7 @@ class Pipeline:
             batch_cache_hit=bundle.batch_hit,
             artifact_paths={"labels": bundle.labels_path,
                             "batch": bundle.batch_path},
-            shapes={"k": bundle.batch.k, "n_pad": bundle.batch.n_pad,
-                    "e_pad": bundle.batch.e_pad},
+            shapes=shapes,
             collectives=collectives,
             accuracy={k: float(v) for k, v in accuracy.items()},
             checkpoint_path=checkpoint_path,
